@@ -74,6 +74,9 @@ def main() -> None:
         if os.path.isdir(args.json):
             parser.error(f"--json path is a directory: {args.json!r}")
     selected = args.suite or list(suites)
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     print("name,us_per_call,derived")
     t0 = time.perf_counter()
     results = {}

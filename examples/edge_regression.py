@@ -1,29 +1,28 @@
-"""Distributed edge scenario: 8 devices sketch their local streams, merge by
-integer addition (psum), and every device trains the same model from the
-merged sketch — optionally with a differentially-private release.
+"""Distributed edge scenario: every device sketches its local stream, the
+sketches merge by integer addition (psum), and every device trains the same
+model from the merged sketch — optionally with a differentially-private
+release.
 
-This script forces 8 host devices, so run it as its own process:
+The mesh spans every device JAX finds (1 or 4 TPU chips, or the CPU):
     PYTHONPATH=src python examples/edge_regression.py
+On the CPU, ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` gives it
+eight devices to split the stream over.
 """
 
-import os
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import Mesh
 
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-import numpy as np  # noqa: E402
-from jax.sharding import Mesh  # noqa: E402
-
-from repro.core import distributed, dfo, erm, losses, lsh, privacy, sketch  # noqa: E402
-from repro.data import datasets  # noqa: E402
+from repro.core import distributed, dfo, erm, losses, lsh, privacy, sketch
+from repro.data import datasets
 
 
 def main() -> None:
     key = jax.random.PRNGKey(0)
     k_data, k_hash, k_fit, k_priv = jax.random.split(key, 4)
 
-    # One global regression problem, observed as 8 device-local streams.
+    # One global regression problem, observed as device-local streams.
     x, y, _ = datasets.make_regression(k_data, n=4096, d=8, noise=0.2,
                                        condition=10)
     xs = (x - x.mean(0)) / (x.std(0) + 1e-8)
@@ -35,7 +34,7 @@ def main() -> None:
     z_scaled, _ = lsh.scale_to_unit_ball(z)
 
     params = lsh.init_srp(k_hash, rows=2048, planes=4, dim=z.shape[1] + 2)
-    mesh = Mesh(np.array(jax.devices()).reshape(8), ("data",))
+    mesh = Mesh(np.array(jax.devices()), ("data",))
 
     # SPMD: local sketch per device + integer all-reduce == merged sketch.
     merged = distributed.sharded_sketch(params, z_scaled, mesh, axis="data")

@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core import dfo, erm, lsh, sketch as sketch_lib
 
 Array = jax.Array
@@ -68,7 +67,7 @@ def sharded_sketch(
         return sketch_lib.Sketch(counts=counts, n=n)
 
     shard_spec = P(axes)
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local_build,
         mesh=mesh,
         in_specs=(P(), shard_spec),
@@ -169,7 +168,7 @@ def fleet_fit(
 
     fleet_spec, replicated = sharding_specs.fleet_specs(axis)
     sharding_specs.check_fleet_divisible(f, mesh, axis)
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(replicated, replicated, replicated,
@@ -278,7 +277,7 @@ def fleet_fit_banked(
 
     bank_spec, replicated = sharding_specs.bank_specs(axis)
     sharding_specs.check_bank_divisible(s, mesh, axis)
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(bank_spec, bank_spec, replicated,

@@ -190,7 +190,7 @@ def make_loss_fn(
         if transform is not None:
             est = transform(est)
         if l2 > 0.0:
-            est = est + l2 * jnp.sum(thetas[..., :d] ** 2, axis=-1)
+            est = est + l2 * lsh.row_sq_norm(thetas[..., :d])
         return est
 
     return jax.jit(loss_fn)

@@ -101,9 +101,28 @@ def srp_codes(params: LSHParams, x: Array) -> Array:
     # (..., dim) @ (dim, R*p) -> (..., R, p): one matmul for all rows/planes.
     r, p, d = params.projections.shape
     w = params.projections.reshape(r * p, d)
-    proj = jnp.einsum("...d,kd->...k", x.astype(jnp.float32), w)
+    proj = jnp.einsum("...d,kd->...k", x.astype(jnp.float32), w,
+                      precision=jax.lax.Precision.HIGHEST)
     bits = (proj.reshape(x.shape[:-1] + (r, p)) > 0).astype(jnp.int32)
     return jnp.einsum("...rp,p->...r", bits, _bit_weights(p))
+
+
+def row_sq_norm(x: Array) -> Array:
+    """``sum(x * x, axis=-1)`` by a pairwise tree of elementwise adds.
+
+    A reduce's rounding depends on how XLA lays the batch out: on TPU the
+    same rows sum differently when they are sliced from a fused host buffer
+    than when they arrive alone, and on CPU when the batch size changes.
+    The hashes below sign-test values built from these norms, so one ulp
+    can move a point to another bucket. Elementwise adds in a fixed order
+    round the same in every program.
+    """
+    sq = x * x
+    while sq.shape[-1] > 1:
+        if sq.shape[-1] % 2:
+            sq = jnp.pad(sq, [(0, 0)] * (sq.ndim - 1) + [(0, 1)])
+        sq = sq[..., 0::2] + sq[..., 1::2]
+    return sq[..., 0]
 
 
 def augment_data(z: Array) -> Array:
@@ -112,16 +131,14 @@ def augment_data(z: Array) -> Array:
     Requires ``|z| <= 1`` (callers pre-scale the dataset); the norm residual is
     clipped at 0 for numerical safety.
     """
-    sq = jnp.sum(z * z, axis=-1, keepdims=True)
-    pad = jnp.sqrt(jnp.clip(1.0 - sq, 0.0, None))
+    pad = jnp.sqrt(jnp.clip(1.0 - row_sq_norm(z), 0.0, None))[..., None]
     zeros = jnp.zeros_like(pad)
     return jnp.concatenate([z, zeros, pad], axis=-1)
 
 
 def augment_query(q: Array) -> Array:
     """Asymmetric-LSH query augmentation ``q -> [q, sqrt(1 - |q|^2), 0]``."""
-    sq = jnp.sum(q * q, axis=-1, keepdims=True)
-    pad = jnp.sqrt(jnp.clip(1.0 - sq, 0.0, None))
+    pad = jnp.sqrt(jnp.clip(1.0 - row_sq_norm(q), 0.0, None))[..., None]
     zeros = jnp.zeros_like(pad)
     return jnp.concatenate([q, pad, zeros], axis=-1)
 
@@ -152,7 +169,7 @@ def normalize_query(q: Array) -> Array:
     Zeros of ``<q, z>`` are invariant under this scaling, so the surrogate
     loss keeps the same minimizer (DESIGN.md §7).
     """
-    nrm = jnp.linalg.norm(q, axis=-1, keepdims=True)
+    nrm = jnp.sqrt(row_sq_norm(q))[..., None]
     return q / jnp.maximum(nrm, 1e-12)
 
 

@@ -448,9 +448,8 @@ def _scan_insert(
     init = (jnp.zeros((rows, buckets), carry_dtype),
             jnp.zeros((), dtype=jnp.int32))
     if vary_axes:
-        from repro import compat
-
-        init = jax.tree.map(lambda t: compat.pvary(t, tuple(vary_axes)), init)
+        init = jax.tree.map(
+            lambda t: jax.lax.pcast(t, tuple(vary_axes), to="varying"), init)
     (counts, cnt), _ = jax.lax.scan(step, init, (zp, maskp))
     return counts, cnt
 

@@ -15,6 +15,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core import lsh
 from repro.core.sketch import saturating_cast
 
 Array = jax.Array
@@ -43,7 +44,8 @@ def srp_hash(x: Array, w: Array) -> Array:
     p = w.shape[0]
     codes = jnp.zeros((x.shape[0], w.shape[2]), jnp.int32)
     for j in range(p):
-        proj = x.astype(jnp.float32) @ w[j].astype(jnp.float32)
+        proj = jnp.dot(x.astype(jnp.float32), w[j].astype(jnp.float32),
+                       precision=jax.lax.Precision.HIGHEST)
         codes = codes + ((proj > 0).astype(jnp.int32) << j)
     return codes
 
@@ -132,8 +134,7 @@ def _paired_packed_codes(z: Array, w: Array, pos_shift, neg_shift):
     p, d_aug, r = w.shape
     assert d_aug == d + 2, (d_aug, d)
     z = z.astype(jnp.float32)
-    sq = jnp.sum(z * z, axis=-1, keepdims=True)
-    pad = jnp.sqrt(jnp.clip(1.0 - sq, 0.0, None))  # (n, 1)
+    pad = jnp.sqrt(jnp.clip(1.0 - lsh.row_sq_norm(z), 0.0, None))[:, None]
     za = jnp.concatenate([z, jnp.zeros_like(pad), pad], axis=-1)
     packed = neg_shift is not None
     if packed:
@@ -142,7 +143,9 @@ def _paired_packed_codes(z: Array, w: Array, pos_shift, neg_shift):
         cpos = jnp.zeros((n, r), jnp.int32)
         cneg = jnp.zeros((n, r), jnp.int32)
     for j in range(p):
-        acc = za @ w[j].astype(jnp.float32)  # (n, R) — the only matmul pass
+        # (n, R) — the only matmul pass
+        acc = jnp.dot(za, w[j].astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
         t2 = 2.0 * pad * w[j, d + 1].astype(jnp.float32)[None, :]  # rank-1
         pos = (acc > 0).astype(jnp.int32)
         neg = (acc < t2).astype(jnp.int32)

@@ -3,27 +3,33 @@
 The DFO optimizer issues ~2k sphere queries per step and the quadratic-refine
 polish issues ``3 * (1 + d + d(d+1)/2)`` trust-region samples in one batch;
 this kernel fuses the query-side hashing with the counter gather so a whole
-DFO step is one call. TPU has no fast gather either — the gather is a one-hot
-contraction against the (br, B) counter tile held in VMEM.
+DFO step is one call. TPU has no fast gather either — the gather is a
+bucket-by-bucket select against the counter tile held in VMEM.
 
 Schedule (DESIGN.md §3.3):
   grid = (m/bm, R/br, d/bd); ``k`` (features) fastest, then ``R``.
   - scratch ``acc (p, bm, br)`` accumulates projections over ``k`` for the
     current (query-tile, row-tile) pair;
-  - at the last ``k`` step, codes are packed and the partial sum
-    ``sum_r counts[r, code]`` for this row tile is added to the output;
+  - at the last ``k`` step, codes are packed and, for each bucket ``b``, the
+    lane-dense ``(1, br)`` row of counts at ``b`` is selected where a
+    query's code equals ``b``; the row-tile's partial sum is added to the
+    output;
   - each output block (bm, 1) is revisited across the whole (R, d) subgrid
     and initialized once at the first step, so arbitrarily large query
     batches (m >> 128) stream through without a reference fallback.
 
+Counters enter the kernel bucket-major, ``(B, S, R)`` (the wrapper
+transposes the library's ``(S, R, B)``), so every tile the epilogue touches
+carries the long ``R`` axis in its lanes and the small bucket axis never
+does.
+
 The banked variant (``sketch_query_banked``, DESIGN.md §9) serves S sketches
 that share one hash family: the projection/code pipeline is untouched (one
-matmul pass for all m points) and only the epilogue changes — the counter
-input is the stacked ``(S, br, B)`` row tile and each query row one-hot
-selects its own table (``sel @ counts``, an MXU contraction) before the
-bucket gather. ``S = 1`` reduces to the unbanked epilogue exactly (the
-select matrix is all-ones), and integer counts make the f32 reductions
-order-independent, so the slice agreement is bit-for-bit.
+matmul pass for all m points) and only the epilogue changes — each query row
+one-hot selects its own table (``sel @ counts[b]``, an MXU contraction at
+f32 precision, exact on integer counts) before the bucket select. The lone
+query is the same kernel at ``S = 1``, where the select is the identity and
+is skipped.
 
 Counter tiles may be narrow (int16/int8, DESIGN.md §12): the epilogue lifts
 the tile to f32 right at the gather, so a narrow bank streams S-fold less
@@ -40,10 +46,22 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.storm_sketch import match_vma
+
 Array = jax.Array
 
+HIGHEST = jax.lax.Precision.HIGHEST
 
-def _query_kernel(q_ref, w_ref, c_ref, o_ref, acc_ref, *, planes: int, k_steps: int):
+# VMEM budget for one f32-sized (B, S, br) counter block: the row tile
+# shrinks with S*B until the block fits. The block is double-buffered, so a
+# large bank can still outgrow the 16 MiB of scoped VMEM a v5e grants by
+# default; ``_vmem_limit`` then asks for what the kernel needs.
+_COUNTER_BLOCK_BYTES = 4 << 20
+_LANES = 128
+
+
+def _query_kernel(q_ref, w_ref, c_ref, idx_ref, o_ref, acc_ref, *,
+                  planes: int, k_steps: int):
     j = pl.program_id(1)  # row (R) tile
     k = pl.program_id(2)  # feature (d) tile
 
@@ -59,19 +77,113 @@ def _query_kernel(q_ref, w_ref, c_ref, o_ref, acc_ref, *, planes: int, k_steps: 
     for p in range(planes):
         acc_ref[p, :, :] += jnp.dot(
             q, w_ref[p, :, :].astype(jnp.float32),
-            preferred_element_type=jnp.float32,
+            preferred_element_type=jnp.float32, precision=HIGHEST,
         )
 
     @pl.when(k == k_steps - 1)
     def _epilogue():
-        buckets = c_ref.shape[-1]
+        buckets, s, _ = c_ref.shape
         codes = jnp.zeros(acc_ref.shape[1:], jnp.int32)  # (bm, br)
         for p in range(planes):
             codes += (acc_ref[p, :, :] > 0).astype(jnp.int32) << p
-        iota = jax.lax.broadcasted_iota(jnp.int32, (1, 1, buckets), 2)
-        onehot = (codes[:, :, None] == iota).astype(jnp.float32)  # (bm, br, B)
-        counts = c_ref[...].astype(jnp.float32)  # (br, B)
-        o_ref[...] += jnp.einsum("mrb,rb->m", onehot, counts)[:, None]
+        if s > 1:
+            # Per-query table select: (bm, S) one-hot against the sketch
+            # axis, contracted with each bucket's (S, br) slab on the MXU.
+            # Counts are integers, so the f32 contraction is exact.
+            iota_s = jax.lax.broadcasted_iota(jnp.int32, (1, s), 1)
+            sel = (idx_ref[...] == iota_s).astype(jnp.float32)  # (bm, S)
+        gathered = jnp.zeros(codes.shape, jnp.float32)
+        for b in range(buckets):
+            table = c_ref[b].astype(jnp.float32)  # (S, br)
+            if s > 1:
+                table = jnp.dot(sel, table, preferred_element_type=jnp.float32,
+                                precision=HIGHEST)  # (bm, br)
+            gathered += jnp.where(codes == b, table, 0.0)
+        o_ref[...] += jnp.sum(gathered, axis=1, keepdims=True)
+
+
+def _row_block(block_r: int, r: int, s: int, buckets: int) -> int:
+    """Row tile: at most ``block_r``, shrunk (in whole lane tiles) until the
+    f32 ``(B, S, br)`` counter block fits ``_COUNTER_BLOCK_BYTES``."""
+    fit = _COUNTER_BLOCK_BYTES // (4 * s * buckets) // _LANES * _LANES
+    return min(block_r, r, max(_LANES, fit))
+
+
+def _vmem_limit(s: int, buckets: int, br: int, itemsize: int):
+    """Scoped VMEM to request: the default unless a huge bank's counter
+    block (double-buffered, plus its f32 lift) outgrows it."""
+    need = s * buckets * br * (2 * itemsize + 4) + (8 << 20)
+    return need if need > (16 << 20) else None
+
+
+@functools.partial(
+    jax.jit, static_argnames=("block_m", "block_r", "block_d", "interpret")
+)
+def sketch_query_banked(
+    q: Array,
+    w: Array,
+    counts: Array,
+    sketch_idx: Array,
+    *,
+    block_m: int = 128,
+    block_r: int = 512,
+    block_d: int = 512,
+    interpret: bool = False,
+) -> Array:
+    """Banked RACE query: per-point table select over a stacked counter bank.
+
+    See ``ref.sketch_query_banked``. The VMEM counter block is
+    ``(B, S, br)``, so the row tile shrinks with ``S * B`` (``_row_block``).
+    Narrow counter dtypes cut that block (and the HBM reads feeding it) 2–4x:
+    the tile is loaded at its stored width and lifted to f32 only inside the
+    epilogue gather, bit-equal to the widened bank.
+
+    Args:
+      q: ``(m, d)`` normalized/augmented query vectors; m is unrestricted.
+      w: ``(p, d, R)`` hyperplane normals (one hash family for the bank).
+      counts: ``(S, R, 2**p)`` stacked counters (int32/int16/int8).
+      sketch_idx: ``(m,)`` int32 table index per query point.
+
+    Returns:
+      ``(m,)`` float32 mean count over rows of each point's own table.
+    """
+    m, d = q.shape
+    p, dw, r = w.shape
+    s, _, buckets = counts.shape
+    assert d == dw and counts.shape == (s, r, 1 << p)
+
+    bm = min(block_m, max(8, m))
+    br = _row_block(block_r, r, s, buckets)
+    bd = min(block_d, d)
+    m_pad, r_pad, d_pad = (-m) % bm, (-r) % br, (-d) % bd
+    qp = jnp.pad(q, ((0, m_pad), (0, d_pad)))
+    wp = jnp.pad(w, ((0, 0), (0, d_pad), (0, r_pad)))
+    # Bucket-major counters; padded R rows are zero and contribute 0. Padded
+    # query rows read table 0 and are sliced away below.
+    cp = jnp.pad(jnp.transpose(counts, (2, 0, 1)),
+                 ((0, 0), (0, 0), (0, r_pad)))
+    idxp = jnp.pad(sketch_idx.astype(jnp.int32), (0, m_pad))[:, None]
+    grid = ((m + m_pad) // bm, (r + r_pad) // br, (d + d_pad) // bd)
+    operands, vma = match_vma(qp, wp, cp, idxp)
+
+    out = pl.pallas_call(
+        functools.partial(_query_kernel, planes=p, k_steps=grid[2]),
+        grid=grid,
+        in_specs=[
+            pl.BlockSpec((bm, bd), lambda i, j, k: (i, k)),
+            pl.BlockSpec((p, bd, br), lambda i, j, k: (0, k, j)),
+            pl.BlockSpec((buckets, s, br), lambda i, j, k: (0, 0, j)),
+            pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),
+        ],
+        out_specs=pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((m + m_pad, 1), jnp.float32,
+                                       vma=vma),
+        scratch_shapes=[pltpu.VMEM((p, bm, br), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_vmem_limit(
+            s, buckets, br, counts.dtype.itemsize)),
+        interpret=interpret,
+    )(*operands)
+    return out[:m, 0] / r
 
 
 @functools.partial(
@@ -97,139 +209,8 @@ def sketch_query(
     Returns:
       ``(m,)`` float32 mean count over rows.
     """
-    m, d = q.shape
-    p, dw, r = w.shape
-    assert d == dw and counts.shape == (r, 1 << p)
-
-    bm = min(block_m, max(8, m))
-    br = min(block_r, r)
-    bd = min(block_d, d)
-    m_pad, r_pad, d_pad = (-m) % bm, (-r) % br, (-d) % bd
-    qp = jnp.pad(q, ((0, m_pad), (0, d_pad)))
-    wp = jnp.pad(w, ((0, 0), (0, d_pad), (0, r_pad)))
-    # Padded rows must contribute 0: zero counters for padded R rows.
-    cp = jnp.pad(counts, ((0, r_pad), (0, 0)))
-    grid = ((m + m_pad) // bm, (r + r_pad) // br, (d + d_pad) // bd)
-
-    out = pl.pallas_call(
-        functools.partial(_query_kernel, planes=p, k_steps=grid[2]),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bd), lambda i, j, k: (i, k)),
-            pl.BlockSpec((p, bd, br), lambda i, j, k: (0, k, j)),
-            pl.BlockSpec((br, 1 << p), lambda i, j, k: (j, 0)),
-        ],
-        out_specs=pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((m + m_pad, 1), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((p, bm, br), jnp.float32)],
+    return sketch_query_banked(
+        q, w, counts[None], jnp.zeros((q.shape[0],), jnp.int32),
+        block_m=block_m, block_r=block_r, block_d=block_d,
         interpret=interpret,
-    )(qp, wp, cp)
-    return out[:m, 0] / r
-
-
-def _banked_query_kernel(
-    q_ref, w_ref, c_ref, idx_ref, o_ref, acc_ref, *, planes: int, k_steps: int
-):
-    j = pl.program_id(1)  # row (R) tile
-    k = pl.program_id(2)  # feature (d) tile
-
-    @pl.when(jnp.logical_and(j == 0, k == 0))
-    def _init_out():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    @pl.when(k == 0)
-    def _init_acc():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q = q_ref[...].astype(jnp.float32)  # (bm, bd)
-    for p in range(planes):
-        acc_ref[p, :, :] += jnp.dot(
-            q, w_ref[p, :, :].astype(jnp.float32),
-            preferred_element_type=jnp.float32,
-        )
-
-    @pl.when(k == k_steps - 1)
-    def _epilogue():
-        s, _, buckets = c_ref.shape
-        bm = acc_ref.shape[1]
-        codes = jnp.zeros(acc_ref.shape[1:], jnp.int32)  # (bm, br)
-        for p in range(planes):
-            codes += (acc_ref[p, :, :] > 0).astype(jnp.int32) << p
-        iota_b = jax.lax.broadcasted_iota(jnp.int32, (1, 1, buckets), 2)
-        onehot = (codes[:, :, None] == iota_b).astype(jnp.float32)  # (bm,br,B)
-        # Per-query table select: (bm, S) one-hot against the sketch axis,
-        # contracted with the stacked (S, br*B) tile on the MXU. Counts are
-        # integers, so the extra f32 contraction is exact.
-        iota_s = jax.lax.broadcasted_iota(jnp.int32, (1, s), 1)
-        sel = (idx_ref[...] == iota_s).astype(jnp.float32)  # (bm, S)
-        counts = c_ref[...].astype(jnp.float32).reshape(s, -1)  # (S, br*B)
-        counts_m = jnp.dot(sel, counts,
-                           preferred_element_type=jnp.float32)  # (bm, br*B)
-        gathered = jnp.sum(onehot.reshape(bm, -1) * counts_m, axis=-1)
-        o_ref[...] += gathered[:, None]
-
-
-@functools.partial(
-    jax.jit, static_argnames=("block_m", "block_r", "block_d", "interpret")
-)
-def sketch_query_banked(
-    q: Array,
-    w: Array,
-    counts: Array,
-    sketch_idx: Array,
-    *,
-    block_m: int = 128,
-    block_r: int = 512,
-    block_d: int = 512,
-    interpret: bool = False,
-) -> Array:
-    """Banked RACE query: per-point table select over a stacked counter bank.
-
-    See ``ref.sketch_query_banked``. The VMEM counter tile grows S-fold
-    (``(S, br, B)``), so banks with large ``S * B`` should shrink ``block_r``
-    accordingly; at the serving shapes (S ≤ 64, B = 16) the default tile is
-    ~0.5–2 MB. Narrow counter dtypes cut that tile (and the HBM reads
-    feeding it) 2–4x: the tile is loaded at its stored width and lifted to
-    f32 only inside the epilogue gather, bit-equal to the widened bank.
-
-    Args:
-      q: ``(m, d)`` normalized/augmented query vectors; m is unrestricted.
-      w: ``(p, d, R)`` hyperplane normals (one hash family for the bank).
-      counts: ``(S, R, 2**p)`` stacked counters (int32/int16/int8).
-      sketch_idx: ``(m,)`` int32 table index per query point.
-
-    Returns:
-      ``(m,)`` float32 mean count over rows of each point's own table.
-    """
-    m, d = q.shape
-    p, dw, r = w.shape
-    s = counts.shape[0]
-    assert d == dw and counts.shape == (s, r, 1 << p)
-
-    bm = min(block_m, max(8, m))
-    br = min(block_r, r)
-    bd = min(block_d, d)
-    m_pad, r_pad, d_pad = (-m) % bm, (-r) % br, (-d) % bd
-    qp = jnp.pad(q, ((0, m_pad), (0, d_pad)))
-    wp = jnp.pad(w, ((0, 0), (0, d_pad), (0, r_pad)))
-    # Padded rows must contribute 0: zero counters for padded R rows. Padded
-    # query rows read table 0 and are sliced away below.
-    cp = jnp.pad(counts, ((0, 0), (0, r_pad), (0, 0)))
-    idxp = jnp.pad(sketch_idx.astype(jnp.int32), (0, m_pad))[:, None]
-    grid = ((m + m_pad) // bm, (r + r_pad) // br, (d + d_pad) // bd)
-
-    out = pl.pallas_call(
-        functools.partial(_banked_query_kernel, planes=p, k_steps=grid[2]),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bd), lambda i, j, k: (i, k)),
-            pl.BlockSpec((p, bd, br), lambda i, j, k: (0, k, j)),
-            pl.BlockSpec((s, br, 1 << p), lambda i, j, k: (0, j, 0)),
-            pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((m + m_pad, 1), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((p, bm, br), jnp.float32)],
-        interpret=interpret,
-    )(qp, wp, cp, idxp)
-    return out[:m, 0] / r
+    )

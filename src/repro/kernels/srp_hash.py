@@ -37,6 +37,7 @@ def _srp_hash_kernel(x_ref, w_ref, o_ref, acc_ref, *, planes: int, k_steps: int)
         acc_ref[j, :, :] += jnp.dot(
             x, w_ref[j, :, :].astype(jnp.float32),
             preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
         )
 
     @pl.when(k == k_steps - 1)

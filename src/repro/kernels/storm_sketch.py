@@ -3,19 +3,23 @@
 A GPU implementation scatter-increments the ``R x B`` counter array with
 atomics. TPUs have no fast scatter, so the insert is re-thought for the MXU/
 VPU (DESIGN.md §3): stream data tiles HBM->VMEM, run the ``p`` projection
-matmuls, sign+pack to codes, expand to a one-hot cube and reduce over the
-batch tile into a VMEM-resident ``(br, B)`` accumulator. Codes and one-hots
-never touch HBM; each data element is read exactly once.
+matmuls, sign+pack to codes, and count each bucket's hits over the batch tile
+into a VMEM-resident histogram. Codes never touch HBM; each data element is
+read exactly once.
 
-Schedule (shared by both kernels):
-  grid = (R/br, n/bn, d/bd); ``k`` (features) fastest, then ``n``.
+Schedule (one kernel serves all four entry points):
+  grid = (S, R/br, n/bn, d/bd); ``k`` (features) fastest, then ``n``.
   - scratch ``acc (p, bn, br)`` accumulates projections over ``k``;
-  - on the last ``k`` step the epilogue packs codes and adds the masked
-    one-hot histogram of the tile into a VMEM-resident int32 ``(br, B)``
-    histogram scratch;
+  - on the last ``k`` step the epilogue packs codes (masked rows get code
+    -1, which no bucket matches) and, bucket by bucket, adds the tile's hit
+    counts to a bucket-major int32 ``(B, br)`` histogram scratch. Rows of
+    that scratch are lane-dense ``br``-wide vectors, so no value carries the
+    small bucket axis in its lanes;
   - on the last ``(n, k)`` step the write-back epilogue casts the int32
     histogram to ``out_dtype`` — saturating at the dtype range for narrow
-    counters (DESIGN.md §6/§12) — and stores the output block ONCE.
+    counters (DESIGN.md §6/§12) — and stores the ``(B, br)`` output block
+    ONCE. The wrapper transposes the bucket-major ``(S, B, R)`` result to the
+    library's ``(S, R, B)`` layout.
 
 The int32-scratch + one-``saturating_cast``-epilogue split is what makes
 narrow counter tiles (``out_dtype=int16/int8``) native: the accumulator can
@@ -23,20 +27,22 @@ never wrap mid-batch, the HBM output (and hence the resident bank) shrinks
 2–4x, and the result is bit-identical to ``saturating_cast`` of the int32
 histogram — the same widen/saturate discipline ``core/sketch.py`` owns.
 
-``paired_hash_histogram`` is the antithetic PRP insert (DESIGN.md §3.2): the
-augmented pair ``aug(±z) = [±z, 0, pad]`` shares the padding coordinate, so
-the epilogue derives the negative-side projections from the accumulator and a
+``paired=True`` is the antithetic PRP insert (DESIGN.md §3.2): the augmented
+pair ``aug(±z) = [±z, 0, pad]`` shares the padding coordinate, so the
+epilogue derives the negative-side projections from the accumulator and a
 rank-1 ``pad ⊗ w_pad`` correction — both code sets from one projection pass,
 halving MXU flops and HBM reads per insert versus two single-sided calls.
 
-The ``*_banked`` variants (DESIGN.md §10) prepend a sketch axis to the grid:
-``(S, n, d)``-stacked tenant batches produce an ``(S, R, B)`` counter stack
-in ONE kernel launch. The hash family is shared across the bank, so the
-weight blocks are reused unchanged for every ``s``; only the data/mask/output
-index maps gain the leading coordinate, and the per-``(s, r)`` histogram
-scratch is revisited across the ``(n, k)`` subgrid exactly as in the
-lone-sketch schedule — slice ``s`` of the result is the lone-sketch kernel's
-output for tenant ``s``, tile for tile.
+The sketch axis ``S`` leads the grid (DESIGN.md §10): ``(S, n, d)``-stacked
+tenant batches produce an ``(S, R, B)`` counter stack in ONE launch. The hash
+family is shared across the bank, so the weight blocks are reused unchanged
+for every ``s``; slice ``s`` of the result is the lone-sketch output for
+tenant ``s``, tile for tile. The lone-sketch entry points run the same
+kernel at ``S = 1``.
+
+The projection matmuls run at ``Precision.HIGHEST`` (f32 contraction on the
+MXU) so kernel codes match the f32 reference hash, which pins the same
+precision (``kernels/ref.py``, ``core/lsh.py``).
 """
 
 from __future__ import annotations
@@ -48,7 +54,28 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core import lsh
+
 Array = jax.Array
+
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def match_vma(*operands):
+    """Type kernel operands for ``jax.shard_map``'s varying-axes check.
+
+    Returns the operands, each cast varying over every mesh axis any of
+    them varies over (a replicated hash family beside a per-device data
+    shard), and that axis set, which the ``pallas_call`` output must carry.
+    Outside ``shard_map`` the set is empty and nothing changes.
+    """
+    vma = frozenset().union(*(jax.typeof(a).vma for a in operands))
+
+    def cast(a):
+        missing = tuple(vma - jax.typeof(a).vma)
+        return jax.lax.pcast(a, missing, to="varying") if missing else a
+
+    return [cast(a) for a in operands], vma
 
 
 def _cast_out(hist32: Array, out_dtype) -> Array:
@@ -66,12 +93,14 @@ def _cast_out(hist32: Array, out_dtype) -> Array:
     return jnp.clip(hist32, info.min, info.max).astype(dtype)
 
 
-def _hash_histogram_kernel(
-    x_ref, w_ref, m_ref, o_ref, acc_ref, hist_ref, *, planes: int,
-    n_steps: int, k_steps: int, out_dtype,
-):
-    n_i = pl.program_id(1)
-    k = pl.program_id(2)
+def _insert_kernel(*refs, planes: int, paired: bool, tail: int,
+                   n_steps: int, k_steps: int, out_dtype):
+    if paired:
+        x_ref, w_ref, wp_ref, o_ref, acc_ref, hist_ref = refs
+    else:
+        x_ref, w_ref, o_ref, acc_ref, hist_ref = refs
+    n_i = pl.program_id(2)
+    k = pl.program_id(3)
 
     @pl.when(jnp.logical_and(n_i == 0, k == 0))
     def _init_hist():
@@ -81,34 +110,118 @@ def _hash_histogram_kernel(
     def _init_acc():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...].astype(jnp.float32)  # (bn, bd)
+    x = x_ref[0].astype(jnp.float32)  # (bn, bd) — this sketch's data tile
     for j in range(planes):
         acc_ref[j, :, :] += jnp.dot(
             x, w_ref[j, :, :].astype(jnp.float32),
-            preferred_element_type=jnp.float32,
+            preferred_element_type=jnp.float32, precision=HIGHEST,
         )
 
     @pl.when(k == k_steps - 1)
     def _epilogue():
-        buckets = hist_ref.shape[-1]
+        # The last feature tile carries [pad, mask] (paired) or [mask] at
+        # column ``tail`` (see _banked_insert).
         codes = jnp.zeros(acc_ref.shape[1:], jnp.int32)  # (bn, br)
         for j in range(planes):
             codes += (acc_ref[j, :, :] > 0).astype(jnp.int32) << j
-        iota = jax.lax.broadcasted_iota(jnp.int32, (1, 1, buckets), 2)
-        onehot = (codes[:, :, None] == iota).astype(jnp.float32)
-        masked = onehot * m_ref[...].astype(jnp.float32)[:, None, None]
-        hist_ref[...] += jnp.sum(masked, axis=0).astype(jnp.int32)  # (br, B)
+        code_sets = [codes]
+        if paired:
+            pad = x[:, tail:tail + 1]  # (bn, 1)
+            codes_n = jnp.zeros(acc_ref.shape[1:], jnp.int32)
+            for j in range(planes):
+                acc = acc_ref[j, :, :]  # proj(aug(z)) = s + t
+                t2 = 2.0 * pad * wp_ref[j, :, :].astype(jnp.float32)
+                codes_n += ((t2 - acc) > 0).astype(jnp.int32) << j
+            code_sets.append(codes_n)  # proj(aug(-z)) = 2t - proj(aug(z))
+        m_at = tail + 1 if paired else tail
+        valid = x[:, m_at:m_at + 1] > 0  # (bn, 1)
+        code_sets = [jnp.where(valid, c, -1) for c in code_sets]
+        for b in range(hist_ref.shape[0]):
+            hits = sum((c == b).astype(jnp.float32) for c in code_sets)
+            hist_ref[b:b + 1, :] += jnp.sum(
+                hits, axis=0, keepdims=True).astype(jnp.int32)
 
     @pl.when(jnp.logical_and(n_i == n_steps - 1, k == k_steps - 1))
     def _writeback():
-        o_ref[...] = _cast_out(hist_ref[...], out_dtype)
+        o_ref[0] = _cast_out(hist_ref[...], out_dtype)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("block_n", "block_r", "block_d", "out_dtype",
-                     "interpret"),
-)
+def _banked_insert(x, w, mask, *, paired, block_n, block_r, block_d,
+                   out_dtype, interpret):
+    """``(S, n, d)`` stack -> ``(S, R, B)`` histograms (see module doc).
+
+    The per-row scalars the epilogue needs — the PRP pad coordinate and the
+    validity mask — ride as extra feature columns with zero hyperplane
+    weight (adding exact zeros leaves every projection bit-identical), at
+    the start of the last feature tile. A separate ``(S, n, 1)`` operand
+    would be padded 128-fold in HBM, its minor axis filling a lane tile.
+    """
+    s, n, d = x.shape
+    p, d_in, r = w.shape
+    buckets = 1 << p
+    mask = mask.astype(jnp.float32)
+    if paired:
+        assert d_in == d + 2, (d_in, d)
+        z = x.astype(jnp.float32)
+        pad = jnp.sqrt(jnp.clip(1.0 - lsh.row_sq_norm(z), 0.0, None))
+        # aug(z) = [z, 0, pad], then the [pad, mask] tail.
+        x, aug, tail = z, [jnp.zeros_like(pad), pad], [pad, mask]
+    else:
+        assert d == d_in, (d, d_in)
+        aug, tail = [], [mask.astype(x.dtype)]
+    # Columns are stacked from (S, n) rows, never built as (S, n, 1).
+    width = d_in + len(tail)
+    if width <= block_d:  # one feature tile: the tail follows the features
+        bd, tail_at = width, d_in
+        x = jnp.concatenate([x, jnp.stack(aug + tail, axis=-1)], axis=-1)
+    else:  # the tail opens a last tile of its own
+        bd, tail_at = block_d, 0
+        if aug:
+            x = jnp.concatenate([x, jnp.stack(aug, axis=-1)], axis=-1)
+        x = jnp.concatenate([jnp.pad(x, ((0, 0), (0, 0), (0, (-d_in) % bd))),
+                             jnp.stack(tail, axis=-1)], axis=-1)
+
+    bn = min(block_n, max(8, n))
+    br = min(block_r, r)
+    n_pad, r_pad, d_pad = (-n) % bn, (-r) % br, (-x.shape[-1]) % bd
+    xp = jnp.pad(x, ((0, 0), (0, n_pad), (0, d_pad)))  # pad rows masked out
+    wp = jnp.pad(w, ((0, 0), (0, xp.shape[-1] - d_in), (0, r_pad)))
+    operands = [xp, wp]
+    in_specs = [
+        pl.BlockSpec((1, bn, bd), lambda si, i, j, k: (si, j, k)),
+        pl.BlockSpec((p, bd, br), lambda si, i, j, k: (0, k, i)),
+    ]
+    if paired:
+        operands.append(jnp.pad(w[:, d_in - 1:d_in, :],
+                                ((0, 0), (0, 0), (0, r_pad))))
+        in_specs.append(pl.BlockSpec((p, 1, br),
+                                     lambda si, i, j, k: (0, 0, i)))
+    grid = (s, (r + r_pad) // br, (n + n_pad) // bn, xp.shape[-1] // bd)
+    operands, vma = match_vma(*operands)
+
+    out = pl.pallas_call(
+        functools.partial(_insert_kernel, planes=p, paired=paired,
+                          tail=tail_at, n_steps=grid[2], k_steps=grid[3],
+                          out_dtype=out_dtype),
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, buckets, br),
+                               lambda si, i, j, k: (si, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((s, buckets, r + r_pad),
+                                       jnp.dtype(out_dtype), vma=vma),
+        scratch_shapes=[
+            pltpu.VMEM((p, bn, br), jnp.float32),
+            pltpu.VMEM((buckets, br), jnp.int32),
+        ],
+        interpret=interpret,
+    )(*operands)
+    return jnp.swapaxes(out, 1, 2)[:, :r]
+
+
+_STATIC = ("block_n", "block_r", "block_d", "out_dtype", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def hash_histogram(
     x: Array,
     w: Array,
@@ -132,90 +245,12 @@ def hash_histogram(
     Returns:
       ``(R, 2**p)`` counts in ``out_dtype``.
     """
-    n, d = x.shape
-    p, dw, r = w.shape
-    assert d == dw, (d, dw)
-    buckets = 1 << p
-
-    bn = min(block_n, max(8, n))
-    br = min(block_r, r)
-    bd = min(block_d, d)
-    n_pad, r_pad, d_pad = (-n) % bn, (-r) % br, (-d) % bd
-    xp = jnp.pad(x, ((0, n_pad), (0, d_pad)))
-    wp = jnp.pad(w, ((0, 0), (0, d_pad), (0, r_pad)))
-    mp = jnp.pad(mask.astype(jnp.float32), (0, n_pad))  # pad rows masked out
-    grid = ((r + r_pad) // br, (n + n_pad) // bn, (d + d_pad) // bd)
-
-    out = pl.pallas_call(
-        functools.partial(_hash_histogram_kernel, planes=p, n_steps=grid[1],
-                          k_steps=grid[2], out_dtype=out_dtype),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bn, bd), lambda i, j, k: (j, k)),
-            pl.BlockSpec((p, bd, br), lambda i, j, k: (0, k, i)),
-            pl.BlockSpec((bn,), lambda i, j, k: (j,)),
-        ],
-        out_specs=pl.BlockSpec((br, buckets), lambda i, j, k: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((r + r_pad, buckets),
-                                       jnp.dtype(out_dtype)),
-        scratch_shapes=[
-            pltpu.VMEM((p, bn, br), jnp.float32),
-            pltpu.VMEM((br, buckets), jnp.int32),
-        ],
-        interpret=interpret,
-    )(xp, wp, mp)
-    return out[:r]
+    return _banked_insert(x[None], w, mask[None], paired=False,
+                          block_n=block_n, block_r=block_r, block_d=block_d,
+                          out_dtype=out_dtype, interpret=interpret)[0]
 
 
-def _paired_hash_histogram_kernel(
-    x_ref, w_ref, pad_ref, wp_ref, m_ref, o_ref, acc_ref, hist_ref, *,
-    planes: int, n_steps: int, k_steps: int, out_dtype,
-):
-    n_i = pl.program_id(1)
-    k = pl.program_id(2)
-
-    @pl.when(jnp.logical_and(n_i == 0, k == 0))
-    def _init_hist():
-        hist_ref[...] = jnp.zeros_like(hist_ref)
-
-    @pl.when(k == 0)
-    def _init_acc():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    x = x_ref[...].astype(jnp.float32)  # (bn, bd) — augmented features
-    for j in range(planes):
-        acc_ref[j, :, :] += jnp.dot(
-            x, w_ref[j, :, :].astype(jnp.float32),
-            preferred_element_type=jnp.float32,
-        )
-
-    @pl.when(k == k_steps - 1)
-    def _epilogue():
-        buckets = hist_ref.shape[-1]
-        pad = pad_ref[...].astype(jnp.float32)  # (bn, 1)
-        codes_p = jnp.zeros(acc_ref.shape[1:], jnp.int32)  # (bn, br)
-        codes_n = jnp.zeros(acc_ref.shape[1:], jnp.int32)
-        for j in range(planes):
-            acc = acc_ref[j, :, :]  # proj(aug(z)) = s + t
-            t2 = 2.0 * pad * wp_ref[j, :, :].astype(jnp.float32)  # (bn, br)
-            codes_p += (acc > 0).astype(jnp.int32) << j
-            codes_n += ((t2 - acc) > 0).astype(jnp.int32) << j  # proj(aug(-z))
-        iota = jax.lax.broadcasted_iota(jnp.int32, (1, 1, buckets), 2)
-        onehot = (codes_p[:, :, None] == iota).astype(jnp.float32)
-        onehot += (codes_n[:, :, None] == iota).astype(jnp.float32)
-        masked = onehot * m_ref[...].astype(jnp.float32)[:, None, None]
-        hist_ref[...] += jnp.sum(masked, axis=0).astype(jnp.int32)  # (br, B)
-
-    @pl.when(jnp.logical_and(n_i == n_steps - 1, k == k_steps - 1))
-    def _writeback():
-        o_ref[...] = _cast_out(hist_ref[...], out_dtype)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("block_n", "block_r", "block_d", "out_dtype",
-                     "interpret"),
-)
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def paired_hash_histogram(
     z: Array,
     w: Array,
@@ -240,102 +275,12 @@ def paired_hash_histogram(
       ``(R, 2**p)`` counts in ``out_dtype`` (each unmasked point adds 2 per
       row, modulo saturation).
     """
-    n, d = z.shape
-    p, d_aug, r = w.shape
-    assert d_aug == d + 2, (d_aug, d)
-    buckets = 1 << p
-
-    z = z.astype(jnp.float32)
-    sq = jnp.sum(z * z, axis=-1, keepdims=True)
-    pad_col = jnp.sqrt(jnp.clip(1.0 - sq, 0.0, None))  # (n, 1)
-    x_aug = jnp.concatenate([z, jnp.zeros_like(pad_col), pad_col], axis=-1)
-
-    bn = min(block_n, max(8, n))
-    br = min(block_r, r)
-    bd = min(block_d, d_aug)
-    n_pad, r_pad, d_pad = (-n) % bn, (-r) % br, (-d_aug) % bd
-    xp = jnp.pad(x_aug, ((0, n_pad), (0, d_pad)))
-    wp = jnp.pad(w, ((0, 0), (0, d_pad), (0, r_pad)))
-    # Padded rows are masked out; padded pad-column entries of 0 keep the
-    # rank-1 correction zero there.
-    padp = jnp.pad(pad_col, ((0, n_pad), (0, 0)))
-    w_pad = jnp.pad(w[:, d + 1 : d + 2, :], ((0, 0), (0, 0), (0, r_pad)))
-    mp = jnp.pad(mask.astype(jnp.float32), (0, n_pad))
-    grid = ((r + r_pad) // br, (n + n_pad) // bn, (d_aug + d_pad) // bd)
-
-    out = pl.pallas_call(
-        functools.partial(
-            _paired_hash_histogram_kernel, planes=p, n_steps=grid[1],
-            k_steps=grid[2], out_dtype=out_dtype,
-        ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bn, bd), lambda i, j, k: (j, k)),
-            pl.BlockSpec((p, bd, br), lambda i, j, k: (0, k, i)),
-            pl.BlockSpec((bn, 1), lambda i, j, k: (j, 0)),
-            pl.BlockSpec((p, 1, br), lambda i, j, k: (0, 0, i)),
-            pl.BlockSpec((bn,), lambda i, j, k: (j,)),
-        ],
-        out_specs=pl.BlockSpec((br, buckets), lambda i, j, k: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((r + r_pad, buckets),
-                                       jnp.dtype(out_dtype)),
-        scratch_shapes=[
-            pltpu.VMEM((p, bn, br), jnp.float32),
-            pltpu.VMEM((br, buckets), jnp.int32),
-        ],
-        interpret=interpret,
-    )(xp, wp, padp, w_pad, mp)
-    return out[:r]
+    return _banked_insert(z[None], w, mask[None], paired=True,
+                          block_n=block_n, block_r=block_r, block_d=block_d,
+                          out_dtype=out_dtype, interpret=interpret)[0]
 
 
-# ---------------------------------------------------------------------------
-# Banked inserts: one launch histograms an (S, n, d) tenant stack (§10).
-# ---------------------------------------------------------------------------
-
-
-def _hash_histogram_banked_kernel(
-    x_ref, w_ref, m_ref, o_ref, acc_ref, hist_ref, *, planes: int,
-    n_steps: int, k_steps: int, out_dtype,
-):
-    n_i = pl.program_id(2)
-    k = pl.program_id(3)
-
-    @pl.when(jnp.logical_and(n_i == 0, k == 0))
-    def _init_hist():
-        hist_ref[...] = jnp.zeros_like(hist_ref)
-
-    @pl.when(k == 0)
-    def _init_acc():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    x = x_ref[0].astype(jnp.float32)  # (bn, bd) — this sketch's data tile
-    for j in range(planes):
-        acc_ref[j, :, :] += jnp.dot(
-            x, w_ref[j, :, :].astype(jnp.float32),
-            preferred_element_type=jnp.float32,
-        )
-
-    @pl.when(k == k_steps - 1)
-    def _epilogue():
-        buckets = hist_ref.shape[-1]
-        codes = jnp.zeros(acc_ref.shape[1:], jnp.int32)  # (bn, br)
-        for j in range(planes):
-            codes += (acc_ref[j, :, :] > 0).astype(jnp.int32) << j
-        iota = jax.lax.broadcasted_iota(jnp.int32, (1, 1, buckets), 2)
-        onehot = (codes[:, :, None] == iota).astype(jnp.float32)
-        masked = onehot * m_ref[0].astype(jnp.float32)[:, None, None]
-        hist_ref[...] += jnp.sum(masked, axis=0).astype(jnp.int32)  # (br, B)
-
-    @pl.when(jnp.logical_and(n_i == n_steps - 1, k == k_steps - 1))
-    def _writeback():
-        o_ref[0] = _cast_out(hist_ref[...], out_dtype)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("block_n", "block_r", "block_d", "out_dtype",
-                     "interpret"),
-)
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def hash_histogram_banked(
     x: Array,
     w: Array,
@@ -361,92 +306,12 @@ def hash_histogram_banked(
       ``(S, R, 2**p)`` counts in ``out_dtype``; slice ``s`` equals
       ``hash_histogram(x[s], w, mask[s], out_dtype=out_dtype)``.
     """
-    s, n, d = x.shape
-    p, dw, r = w.shape
-    assert d == dw, (d, dw)
-    buckets = 1 << p
-
-    bn = min(block_n, max(8, n))
-    br = min(block_r, r)
-    bd = min(block_d, d)
-    n_pad, r_pad, d_pad = (-n) % bn, (-r) % br, (-d) % bd
-    xp = jnp.pad(x, ((0, 0), (0, n_pad), (0, d_pad)))
-    wp = jnp.pad(w, ((0, 0), (0, d_pad), (0, r_pad)))
-    mp = jnp.pad(mask.astype(jnp.float32), ((0, 0), (0, n_pad)))
-    grid = (s, (r + r_pad) // br, (n + n_pad) // bn, (d + d_pad) // bd)
-
-    out = pl.pallas_call(
-        functools.partial(
-            _hash_histogram_banked_kernel, planes=p, n_steps=grid[2],
-            k_steps=grid[3], out_dtype=out_dtype,
-        ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bn, bd), lambda si, i, j, k: (si, j, k)),
-            pl.BlockSpec((p, bd, br), lambda si, i, j, k: (0, k, i)),
-            pl.BlockSpec((1, bn), lambda si, i, j, k: (si, j)),
-        ],
-        out_specs=pl.BlockSpec((1, br, buckets), lambda si, i, j, k: (si, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((s, r + r_pad, buckets),
-                                       jnp.dtype(out_dtype)),
-        scratch_shapes=[
-            pltpu.VMEM((p, bn, br), jnp.float32),
-            pltpu.VMEM((br, buckets), jnp.int32),
-        ],
-        interpret=interpret,
-    )(xp, wp, mp)
-    return out[:, :r]
+    return _banked_insert(x, w, mask, paired=False, block_n=block_n,
+                          block_r=block_r, block_d=block_d,
+                          out_dtype=out_dtype, interpret=interpret)
 
 
-def _paired_hash_histogram_banked_kernel(
-    x_ref, w_ref, pad_ref, wp_ref, m_ref, o_ref, acc_ref, hist_ref, *,
-    planes: int, n_steps: int, k_steps: int, out_dtype,
-):
-    n_i = pl.program_id(2)
-    k = pl.program_id(3)
-
-    @pl.when(jnp.logical_and(n_i == 0, k == 0))
-    def _init_hist():
-        hist_ref[...] = jnp.zeros_like(hist_ref)
-
-    @pl.when(k == 0)
-    def _init_acc():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    x = x_ref[0].astype(jnp.float32)  # (bn, bd) — augmented features
-    for j in range(planes):
-        acc_ref[j, :, :] += jnp.dot(
-            x, w_ref[j, :, :].astype(jnp.float32),
-            preferred_element_type=jnp.float32,
-        )
-
-    @pl.when(k == k_steps - 1)
-    def _epilogue():
-        buckets = hist_ref.shape[-1]
-        pad = pad_ref[0].astype(jnp.float32)  # (bn, 1)
-        codes_p = jnp.zeros(acc_ref.shape[1:], jnp.int32)  # (bn, br)
-        codes_n = jnp.zeros(acc_ref.shape[1:], jnp.int32)
-        for j in range(planes):
-            acc = acc_ref[j, :, :]  # proj(aug(z)) = s + t
-            t2 = 2.0 * pad * wp_ref[j, :, :].astype(jnp.float32)  # (bn, br)
-            codes_p += (acc > 0).astype(jnp.int32) << j
-            codes_n += ((t2 - acc) > 0).astype(jnp.int32) << j  # proj(aug(-z))
-        iota = jax.lax.broadcasted_iota(jnp.int32, (1, 1, buckets), 2)
-        onehot = (codes_p[:, :, None] == iota).astype(jnp.float32)
-        onehot += (codes_n[:, :, None] == iota).astype(jnp.float32)
-        masked = onehot * m_ref[0].astype(jnp.float32)[:, None, None]
-        hist_ref[...] += jnp.sum(masked, axis=0).astype(jnp.int32)  # (br, B)
-
-    @pl.when(jnp.logical_and(n_i == n_steps - 1, k == k_steps - 1))
-    def _writeback():
-        o_ref[0] = _cast_out(hist_ref[...], out_dtype)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("block_n", "block_r", "block_d", "out_dtype",
-                     "interpret"),
-)
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def paired_hash_histogram_banked(
     z: Array,
     w: Array,
@@ -472,47 +337,6 @@ def paired_hash_histogram_banked(
       ``(S, R, 2**p)`` counts in ``out_dtype``; slice ``s`` equals
       ``paired_hash_histogram(z[s], w, mask[s], out_dtype=out_dtype)``.
     """
-    s, n, d = z.shape
-    p, d_aug, r = w.shape
-    assert d_aug == d + 2, (d_aug, d)
-    buckets = 1 << p
-
-    z = z.astype(jnp.float32)
-    sq = jnp.sum(z * z, axis=-1, keepdims=True)
-    pad_col = jnp.sqrt(jnp.clip(1.0 - sq, 0.0, None))  # (S, n, 1)
-    x_aug = jnp.concatenate([z, jnp.zeros_like(pad_col), pad_col], axis=-1)
-
-    bn = min(block_n, max(8, n))
-    br = min(block_r, r)
-    bd = min(block_d, d_aug)
-    n_pad, r_pad, d_pad = (-n) % bn, (-r) % br, (-d_aug) % bd
-    xp = jnp.pad(x_aug, ((0, 0), (0, n_pad), (0, d_pad)))
-    wp = jnp.pad(w, ((0, 0), (0, d_pad), (0, r_pad)))
-    padp = jnp.pad(pad_col, ((0, 0), (0, n_pad), (0, 0)))
-    w_pad = jnp.pad(w[:, d + 1 : d + 2, :], ((0, 0), (0, 0), (0, r_pad)))
-    mp = jnp.pad(mask.astype(jnp.float32), ((0, 0), (0, n_pad)))
-    grid = (s, (r + r_pad) // br, (n + n_pad) // bn, (d_aug + d_pad) // bd)
-
-    out = pl.pallas_call(
-        functools.partial(
-            _paired_hash_histogram_banked_kernel, planes=p, n_steps=grid[2],
-            k_steps=grid[3], out_dtype=out_dtype,
-        ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bn, bd), lambda si, i, j, k: (si, j, k)),
-            pl.BlockSpec((p, bd, br), lambda si, i, j, k: (0, k, i)),
-            pl.BlockSpec((1, bn, 1), lambda si, i, j, k: (si, j, 0)),
-            pl.BlockSpec((p, 1, br), lambda si, i, j, k: (0, 0, i)),
-            pl.BlockSpec((1, bn), lambda si, i, j, k: (si, j)),
-        ],
-        out_specs=pl.BlockSpec((1, br, buckets), lambda si, i, j, k: (si, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((s, r + r_pad, buckets),
-                                       jnp.dtype(out_dtype)),
-        scratch_shapes=[
-            pltpu.VMEM((p, bn, br), jnp.float32),
-            pltpu.VMEM((br, buckets), jnp.int32),
-        ],
-        interpret=interpret,
-    )(xp, wp, padp, w_pad, mp)
-    return out[:, :r]
+    return _banked_insert(z, w, mask, paired=True, block_n=block_n,
+                          block_r=block_r, block_d=block_d,
+                          out_dtype=out_dtype, interpret=interpret)

@@ -28,6 +28,7 @@ import jax
 import numpy as np
 
 from repro.core import lsh
+from repro.launch import compile_cache
 from repro.serve.storm_gateway import (
     FitRequest, IngestRequest, QueryRequest, StormGateway,
 )
@@ -229,6 +230,7 @@ def main() -> None:
                     help="exhausted tenants: terminal budget_exceeded "
                          "refusal, or serve the last cached release")
     args = ap.parse_args()
+    compile_cache.enable()
 
     policy = None
     if args.epsilon_total is not None:

@@ -31,7 +31,6 @@ from typing import Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.models import layers
 
 Array = jax.Array
@@ -140,7 +139,7 @@ def glr_shardmapped(
     rep4 = P(None, None, None, None)
     rep3 = P(None, None, None)
     out_specs = (spec4, RecurrentState(rep4, rep3)) if return_state else spec4
-    return compat.shard_map(
+    return jax.shard_map(
         lambda qq, kk, vv, lf, gi: glr_sequence_parallel(
             qq, kk, vv, lf, gi, seq_axis=seq_axis, chunk=chunk,
             normalize=normalize, return_state=return_state,
@@ -172,9 +171,11 @@ def glr_sequence_parallel(
     """
     b, _, h, dk = q.shape
     dv = v.shape[-1]
-    state0 = RecurrentState(  # pvary: fresh zeros inside shard_map (vma)
-        s=compat.pvary(jnp.zeros((b, h, dk, dv), jnp.float32), (seq_axis,)),
-        n=compat.pvary(jnp.zeros((b, h, dk), jnp.float32), (seq_axis,)),
+    state0 = RecurrentState(  # pcast: fresh zeros inside shard_map (vma)
+        s=jax.lax.pcast(jnp.zeros((b, h, dk, dv), jnp.float32), (seq_axis,),
+                        to="varying"),
+        n=jax.lax.pcast(jnp.zeros((b, h, dk), jnp.float32), (seq_axis,),
+                        to="varying"),
     )
     (y_raw, ndot), st = glr_chunked(
         q, k, v, log_f, gate_i, state0, chunk=chunk, normalize=normalize,

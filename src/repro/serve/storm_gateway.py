@@ -671,7 +671,6 @@ class StormGateway:
                     counts, n, *unpack_query(flat, 0)))),
             )
 
-        from repro import compat
         from repro.sharding import specs as sharding_specs
 
         bank_spec, _ = sharding_specs.gateway_specs(self.axis)
@@ -685,7 +684,7 @@ class StormGateway:
             self.mesh, sharding_specs.gateway_input_specs(self.axis))
 
         def shard(fn, n_in, n_out):
-            return jax.jit(self._counting(compat.shard_map(
+            return jax.jit(self._counting(jax.shard_map(
                 fn, mesh=self.mesh,
                 in_specs=(bank_spec,) * n_in,
                 out_specs=(bank_spec,) * n_out if n_out > 1 else bank_spec,

@@ -21,8 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
-
 Array = jax.Array
 
 
@@ -72,14 +70,15 @@ def pipeline_forward(
             buf = jax.lax.ppermute(out, axis, perm)
             return (buf, outputs), None
 
-        buf0 = compat.pvary(jnp.zeros_like(x_local[0]), (axis,))
-        outs0 = compat.pvary(jnp.zeros_like(x_local), (axis,))
+        buf0 = jax.lax.pcast(jnp.zeros_like(x_local[0]), (axis,),
+                             to="varying")
+        outs0 = jax.lax.pcast(jnp.zeros_like(x_local), (axis,), to="varying")
         (_, outputs), _ = jax.lax.scan(body, (buf0, outs0),
                                        jnp.arange(steps))
         # only the last stage holds non-zero outputs; psum broadcasts them
         return jax.lax.psum(outputs, axis)
 
-    fn_sharded = compat.shard_map(
+    fn_sharded = jax.shard_map(
         stage_fn,
         mesh=mesh,
         in_specs=(P(axis), P()),

@@ -17,8 +17,9 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import core as jax_core
 from jax.sharding import Mesh
+
+from _jaxpr import all_eqns
 
 from repro.core import dfo, distributed, lsh, regression, sketch as sketch_lib
 
@@ -203,30 +204,11 @@ class TestFleetQueryBatching:
         assert len(scans) == 1
         counter_shape = tuple(sk.counts.shape)
         gathers = [
-            e for e in _all_eqns(scans[0].params["jaxpr"].jaxpr)
+            e for e in all_eqns(scans[0].params["jaxpr"].jaxpr)
             if e.primitive.name == "gather"
             and tuple(e.invars[0].aval.shape) == counter_shape
         ]
         assert len(gathers) == 1, f"expected 1 counter gather, got {len(gathers)}"
-
-
-def _all_eqns(jaxpr):
-    """All eqns of a jaxpr, recursing into call/branch sub-jaxprs."""
-    for eqn in jaxpr.eqns:
-        yield eqn
-        for v in eqn.params.values():
-            for sub in _sub_jaxprs(v):
-                yield from _all_eqns(sub)
-
-
-def _sub_jaxprs(v):
-    if isinstance(v, jax_core.ClosedJaxpr):
-        yield v.jaxpr
-    elif isinstance(v, jax_core.Jaxpr):
-        yield v
-    elif isinstance(v, (list, tuple)):
-        for x in v:
-            yield from _sub_jaxprs(x)
 
 
 class TestHoistedWeights:
@@ -244,7 +226,7 @@ class TestHoistedWeights:
         assert len(scans) == 1
         proj_shape = tuple(params.projections.shape)
         return [
-            e for e in _all_eqns(scans[0].params["jaxpr"].jaxpr)
+            e for e in all_eqns(scans[0].params["jaxpr"].jaxpr)
             if e.primitive.name == "transpose"
             and tuple(e.invars[0].aval.shape) == proj_shape
         ]

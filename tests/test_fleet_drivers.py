@@ -20,32 +20,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax import core as jax_core
 from jax.sharding import Mesh
+
+from _jaxpr import all_eqns
 
 from repro.core import (classification, dfo, fleet, lsh, probes,
                         sketch as sketch_lib)
 from repro.data import datasets
 
 jax.config.update("jax_platform_name", "cpu")
-
-
-def _all_eqns(jaxpr):
-    for eqn in jaxpr.eqns:
-        yield eqn
-        for v in eqn.params.values():
-            for sub in _sub_jaxprs(v):
-                yield from _all_eqns(sub)
-
-
-def _sub_jaxprs(v):
-    if isinstance(v, jax_core.ClosedJaxpr):
-        yield v.jaxpr
-    elif isinstance(v, jax_core.Jaxpr):
-        yield v
-    elif isinstance(v, (list, tuple)):
-        for x in v:
-            yield from _sub_jaxprs(x)
 
 
 def _scan_gathers(loss, dim, counter_shape, f=4, steps=6):
@@ -58,7 +41,7 @@ def _scan_gathers(loss, dim, counter_shape, f=4, steps=6):
     scans = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"]
     assert len(scans) == 1
     return [
-        e for e in _all_eqns(scans[0].params["jaxpr"].jaxpr)
+        e for e in all_eqns(scans[0].params["jaxpr"].jaxpr)
         if e.primitive.name == "gather"
         and tuple(e.invars[0].aval.shape) == tuple(counter_shape)
     ]
@@ -231,7 +214,7 @@ class TestClassificationFleet:
         assert len(scans) == 1
         proj_shape = tuple(fit.params.projections.shape)
         transposes = [
-            e for e in _all_eqns(scans[0].params["jaxpr"].jaxpr)
+            e for e in all_eqns(scans[0].params["jaxpr"].jaxpr)
             if e.primitive.name == "transpose"
             and tuple(e.invars[0].aval.shape) == proj_shape
         ]
@@ -260,14 +243,15 @@ def _probe_dfo(steps=40):
 
 
 def _old_fit_probe_reference(key, state, d_model, dfo_config, l2=3e-2):
-    """The pre-PR-3 fit_probe, inlined verbatim (single iterate, zero-guard
-    selection, un-standardize)."""
+    """The pre-PR-3 fit_probe, inlined (single iterate, zero-guard
+    selection, un-standardize); its ridge sums squares in the library's
+    fixed order (``lsh.row_sq_norm``)."""
 
     def loss_fn(thetas):
         est = sketch_lib.query_theta(state.sketch, state.params, thetas,
                                      paired=True)
         if l2 > 0.0:
-            est = est + l2 * jnp.sum(thetas[..., :d_model] ** 2, axis=-1)
+            est = est + l2 * lsh.row_sq_norm(thetas[..., :d_model])
         return est
 
     proj = dfo.pin_last_coordinate(-1.0)

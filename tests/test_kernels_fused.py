@@ -11,6 +11,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _jaxpr import all_eqns
+
 from repro.core import lsh, sketch as sketch_lib
 from repro.kernels import ops, ref
 from repro.kernels import sketch_query as query_kernel
@@ -100,32 +102,13 @@ def _count_projection_dots(fn, *args, contract_size):
     count too. Used to assert the paired insert runs its projection matmuls
     exactly once per batch.
     """
-    from jax.core import ClosedJaxpr, Jaxpr
-
-    def subjaxprs(v):
-        if isinstance(v, ClosedJaxpr):
-            yield v.jaxpr
-        elif isinstance(v, Jaxpr):
-            yield v
-        elif isinstance(v, (list, tuple)):
-            for item in v:
-                yield from subjaxprs(item)
-
     count = 0
-
-    def walk(jaxpr):
-        nonlocal count
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "dot_general":
-                (lhs_contract, _), _ = eqn.params["dimension_numbers"]
-                shape = eqn.invars[0].aval.shape
-                if any(shape[i] == contract_size for i in lhs_contract):
-                    count += 1
-            for v in eqn.params.values():
-                for sub in subjaxprs(v):
-                    walk(sub)
-
-    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    for eqn in all_eqns(jax.make_jaxpr(fn)(*args).jaxpr):
+        if eqn.primitive.name == "dot_general":
+            (lhs_contract, _), _ = eqn.params["dimension_numbers"]
+            shape = eqn.invars[0].aval.shape
+            if any(shape[i] == contract_size for i in lhs_contract):
+                count += 1
     return count
 
 
