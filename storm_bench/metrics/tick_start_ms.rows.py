@@ -1,0 +1,8 @@
+"""Mean host milliseconds of the gateway's tick_start (packing and dispatch)
+over the window's ingest-carrying ticks."""
+
+from storm_bench.layers import ROWS, tick_start_ms
+
+
+def read(run):
+    return tick_start_ms(run, ROWS)
