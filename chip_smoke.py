@@ -78,22 +78,18 @@ def drive(gw: StormGateway, seed: int):
 
     Returns the rows each tenant sent, every query as ``rid -> (tenant,
     thetas, tick)``, every answer as ``rid -> (losses, tick)``, the counters
-    after each tick (host copies), and the seconds of each traffic tick.
+    after each tick (host copies).
     """
     rng = np.random.default_rng(seed)
     rids = itertools.count()
     rows = [[] for _ in range(TENANTS)]
-    queries, answers, snaps, tick_s = {}, {}, [], []
+    queries, answers, snaps = {}, {}, []
 
     def tick(i):
-        t0 = time.perf_counter()
         report = gw.tick()
-        jax.block_until_ready(gw.bank.counts)
-        seconds = time.perf_counter() - t0
         for res in report.results:
             answers[res.rid] = (res.losses, i)
         snaps.append((np.asarray(gw.bank.counts), np.asarray(gw.bank.n)))
-        return seconds
 
     for i in range(TICKS):
         reqs = synth_traffic(rng, rids, TENANTS, DIM, INGEST_RATE, QUERY_RATE)
@@ -103,14 +99,14 @@ def drive(gw: StormGateway, seed: int):
             else:
                 queries[r.rid] = (r.tenant, r.thetas, i)
         gw.submit_many(reqs)
-        tick_s.append(tick(i))
+        tick(i)
     i = TICKS
     while gw.pending:  # rows beyond a tick's slots spill into later ticks
         tick(i)
         i += 1
     rows = [np.concatenate(r) if r else np.zeros((0, DIM), np.float32)
             for r in rows]
-    return rows, queries, answers, snaps, tick_s
+    return rows, queries, answers, snaps
 
 
 def oracle_counts(rows, w, cpu) -> np.ndarray:
@@ -180,8 +176,7 @@ def gateway_phase(params, seed: int, cpu) -> None:
     check("tpu_custom_call" in compiled.as_text(),
           "the full tick program runs the Pallas kernels (tpu_custom_call)")
 
-    rows, queries, answers, snaps, tick_s = drive(gw, seed)
-    setup("tick seconds (first includes compile)", [round(s, 4) for s in tick_s])
+    rows, queries, answers, snaps = drive(gw, seed)
     counts, n = snaps[-1]
     sent = np.array([len(r) for r in rows])
     check(np.array_equal(n, sent), f"tenant n equals rows sent "
@@ -281,9 +276,7 @@ def mesh_phase(params, seed: int) -> None:
     runs = {}
     for name, m in (("mesh", mesh), ("chip 0", None)):
         gw = make_gateway(params, mesh=m)
-        _, _, answers, snaps, tick_s = drive(gw, seed)
-        setup(f"{name} gateway tick seconds (first includes compile)",
-              [round(s, 4) for s in tick_s])
+        _, _, answers, snaps = drive(gw, seed)
         runs[name] = (answers, snaps[-1])
     (a_ans, (a_counts, a_n)), (b_ans, (b_counts, b_n)) = runs.values()
     moved = int(np.abs(a_counts.astype(np.int64) - b_counts).sum()) // 2

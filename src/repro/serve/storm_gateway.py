@@ -59,6 +59,11 @@ so a mesh splits tenants across devices exactly like
 (``sharding.specs.gateway_specs``): each device owns its tenants' tables and
 exactly those tenants' tick slots — zero per-tick communication.
 
+Tracing (DESIGN.md §11.5): the tick's stages record ``storm.gw.*`` spans
+(``jax.profiler.TraceAnnotation``) at tick granularity, and collections of
+the oldest generation a ``storm.gc`` span; they cost an enter and an exit
+each, and record nothing unless a profiler session is active.
+
 Correctness contract (pinned in ``tests/test_serve_gateway.py`` and
 ``tests/test_serve_async.py``): a tenant's counters after any interleaving
 of gateway ticks are bit-identical to the standalone ``sketch_dataset``
@@ -71,6 +76,9 @@ counters, and result ordering included.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import gc
+import time
 from collections import defaultdict, deque
 from typing import Callable, Deque, Dict, List, Optional, Sequence, Union
 
@@ -83,6 +91,35 @@ from repro.core import (dfo, erm, fleet, losses, lsh,
 from repro.kernels import ops
 
 Array = jax.Array
+span = jax.profiler.TraceAnnotation
+
+
+class _GcSpan:
+    """``gc.callbacks`` hook: a ``storm.gc`` span over each collection of
+    the oldest generation, the one long enough to stall a tick. Younger
+    collections return at once."""
+
+    def __init__(self):
+        self._open = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if info["generation"] != 2:
+            return
+        if phase == "start":
+            self._open = span("storm.gc")
+            self._open.__enter__()
+        elif self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = None
+
+
+_GC_SPAN = _GcSpan()
+
+
+def _wait_ms(queue) -> float:
+    """Milliseconds the head of a FIFO queue, its oldest request, has
+    waited since its submit."""
+    return (time.perf_counter() - queue[0].enqueued) * 1e3
 
 
 class Backpressure(RuntimeError):
@@ -231,6 +268,7 @@ class TickReport:
 class _PendingIngest:
     req: IngestRequest
     cursor: int = 0
+    enqueued: float = dataclasses.field(default_factory=time.perf_counter)
 
 
 @dataclasses.dataclass
@@ -239,6 +277,7 @@ class _PendingQuery:
     cursor: int = 0
     out: Optional[np.ndarray] = None
     status: str = "ok"
+    enqueued: float = dataclasses.field(default_factory=time.perf_counter)
 
 
 @dataclasses.dataclass
@@ -249,6 +288,8 @@ class InflightTick:
     time; ``est`` is the only device future a finish must wait on, and
     ``placements``/``completes``/``ingest_done`` are the host-side
     bookkeeping that turns the readback into :class:`TickReport` entries.
+    :meth:`StormGateway.tick_finish` consumes it: the bookkeeping is
+    released once the report is built.
     """
 
     tick: int
@@ -444,6 +485,8 @@ class StormGateway:
             self._build_ticks()
         if self._private:
             self._tick_query_private = self._build_private_tick()
+        if _GC_SPAN not in gc.callbacks:
+            gc.callbacks.append(_GC_SPAN)
 
     # -- request plumbing ---------------------------------------------------
 
@@ -580,15 +623,21 @@ class StormGateway:
     # -- the fused tick -----------------------------------------------------
 
     def _counting(self, fn):
-        """Bump the fallback trace counter when ``fn``'s body is traced.
+        """Bump the fallback trace counter, under a ``storm.gw.trace``
+        span, when ``fn``'s body is traced.
 
-        The increment is a Python side effect, so under ``jax.jit`` it runs
-        once per trace (cache miss), never per call — exactly the event
-        ``trace_count`` wants when ``_cache_size`` is unavailable.
+        Both are Python side effects, so under ``jax.jit`` they run once per
+        trace (cache miss), never per call — exactly the event
+        ``trace_count`` wants when ``_cache_size`` is unavailable, and one
+        span per compile in a profile (the span covers the tracing, not
+        XLA's compile after it). The wrapper keeps ``fn``'s name, which
+        ``jax.jit`` gives the program.
         """
+        @functools.wraps(fn)
         def wrapped(*args):
             self._trace_events += 1
-            return fn(*args)
+            with span("storm.gw.trace", program=fn.__name__):
+                return fn(*args)
         return wrapped
 
     def _build_ticks(self):
@@ -635,16 +684,6 @@ class StormGateway:
             )
             return jnp.where(qmask > 0, est, 0.0)
 
-        def tick_full(counts, n, zbuf, zmask, qbuf, qmask):
-            counts, n = ingest_half(counts, n, zbuf, zmask)
-            return counts, n, query_half(counts, n, qbuf, qmask)
-
-        def tick_ingest(counts, n, zbuf, zmask):
-            return ingest_half(counts, n, zbuf, zmask)
-
-        def tick_query(counts, n, qbuf, qmask):
-            return query_half(counts, n, qbuf, qmask)
-
         if self.mesh is None:
             # Meshless fast path: ONE fused host->device transfer per tick.
             # The flat buffer is [zbuf | zmask | qbuf | qmask] (the suffix a
@@ -661,15 +700,29 @@ class StormGateway:
                 return (flat[off:q_end].reshape(s * q_cap, dim),
                         flat[q_end:q_end + s * q_cap])
 
-            return (
-                jax.jit(self._counting(lambda counts, n, flat: tick_full(
-                    counts, n, *unpack_ingest(flat),
-                    *unpack_query(flat, zm_end)))),
-                jax.jit(self._counting(lambda counts, n, flat: tick_ingest(
-                    counts, n, *unpack_ingest(flat)))),
-                jax.jit(self._counting(lambda counts, n, flat: tick_query(
-                    counts, n, *unpack_query(flat, 0)))),
-            )
+            def tick_full(counts, n, flat):
+                counts, n = ingest_half(counts, n, *unpack_ingest(flat))
+                return counts, n, query_half(counts, n,
+                                             *unpack_query(flat, zm_end))
+
+            def tick_ingest(counts, n, flat):
+                return ingest_half(counts, n, *unpack_ingest(flat))
+
+            def tick_query(counts, n, flat):
+                return query_half(counts, n, *unpack_query(flat, 0))
+
+            return tuple(jax.jit(self._counting(f))
+                         for f in (tick_full, tick_ingest, tick_query))
+
+        def tick_full(counts, n, zbuf, zmask, qbuf, qmask):
+            counts, n = ingest_half(counts, n, zbuf, zmask)
+            return counts, n, query_half(counts, n, qbuf, qmask)
+
+        def tick_ingest(counts, n, zbuf, zmask):
+            return ingest_half(counts, n, zbuf, zmask)
+
+        def tick_query(counts, n, qbuf, qmask):
+            return query_half(counts, n, qbuf, qmask)
 
         from repro.sharding import specs as sharding_specs
 
@@ -876,74 +929,105 @@ class StormGateway:
             return InflightTick(tick=self.ticks, est=None, placements=[],
                                 completes=[], ingest_done=[], rows=0,
                                 points=0)
-        zbuf, zmask, rows, ingest_done = self._pack_ingest()
-        plans: Dict[int, privacy_lib.ReadPlan] = {}
-        refused: List[_PendingQuery] = []
-        if self._private:
-            # Host version tracking: the packed rows ARE this tick's
-            # inserts, so versions advance exactly like the device n does.
-            if rows:
-                per_slot = zmask.sum(axis=1)
-                for slot in np.nonzero(per_slot)[0]:
-                    self._rows_of[self._privacy_key_of(int(slot))] += \
-                        int(per_slot[slot])
-            plans = self._plan_private_reads()
-            refused = self._refuse_queries(
-                {s for s, p in plans.items() if p.status == "refuse"})
-        qbuf, qmask, placements, completes = self._pack_queries()
-        if refused:
-            completes = refused + completes
-        for st, _, t, _, _ in placements:
-            if t in plans and plans[t].status == "stale":
-                st.status = "stale"
+        rows, ingest_done = 0, []
+        if self._ingest_q:
+            with span("storm.gw.pack_ingest", requests=len(self._ingest_q),
+                      oldest_wait_ms=_wait_ms(self._ingest_q)) as sp:
+                zbuf, zmask, rows, ingest_done = self._pack_ingest()
+                sp.set_metadata(rows=rows)
+                if self._private and rows:
+                    # Host version tracking: the packed rows ARE this
+                    # tick's inserts, so versions advance exactly like the
+                    # device n does.
+                    per_slot = zmask.sum(axis=1)
+                    for slot in np.nonzero(per_slot)[0]:
+                        self._rows_of[self._privacy_key_of(int(slot))] += \
+                            int(per_slot[slot])
+        placements, completes, points = [], [], 0
+        if self._query_q:
+            with span("storm.gw.pack_queries", requests=len(self._query_q),
+                      oldest_wait_ms=_wait_ms(self._query_q)) as sp:
+                if self._private:
+                    plans = self._plan_private_reads()
+                    refused = self._refuse_queries(
+                        {s for s, p in plans.items() if p.status == "refuse"})
+                qbuf, qmask, placements, completes = self._pack_queries()
+                if self._private:
+                    completes = refused + completes
+                    for st, _, t, _, _ in placements:
+                        if t in plans and plans[t].status == "stale":
+                            st.status = "stale"
+                    if placements:
+                        noise, fresh, n_used = \
+                            self._private_query_buffers(plans)
+                points = sum(take for *_, take in placements)
+                sp.set_metadata(points=points)
         do_ingest, do_query = rows > 0, bool(placements)
         est = None
         if self._private:
             if do_ingest:
-                flat = np.concatenate([zbuf.ravel(), zmask.ravel()])
-                self._counts, self._n = self._tick_ingest(
-                    self._counts, self._n, flat)
+                self._counts, self._n = self._launch(
+                    self._tick_ingest, self._counts, self._n,
+                    self._flat(zbuf, zmask))
             if do_query:
-                noise, fresh, n_used = self._private_query_buffers(plans)
-                flat = np.concatenate([qbuf.ravel(), qmask.ravel(),
-                                       noise.ravel(), fresh])
-                self._release_buf, est = self._tick_query_private(
-                    self._counts, self._release_buf, flat, n_used)
+                self._release_buf, est = self._launch(
+                    self._tick_query_private, self._counts,
+                    self._release_buf, self._flat(qbuf, qmask, noise, fresh),
+                    n_used)
                 for slot, plan in plans.items():
                     if plan.status == "fresh":
                         self.private_view.mark_resident(
                             self._privacy_key_of(slot))
         elif self.mesh is None:
             if do_ingest and do_query:
-                flat = np.concatenate([zbuf.ravel(), zmask.ravel(),
-                                       qbuf.ravel(), qmask.ravel()])
-                self._counts, self._n, est = self._tick_full(
-                    self._counts, self._n, flat)
+                self._counts, self._n, est = self._launch(
+                    self._tick_full, self._counts, self._n,
+                    self._flat(zbuf, zmask, qbuf, qmask))
             elif do_ingest:
-                flat = np.concatenate([zbuf.ravel(), zmask.ravel()])
-                self._counts, self._n = self._tick_ingest(
-                    self._counts, self._n, flat)
+                self._counts, self._n = self._launch(
+                    self._tick_ingest, self._counts, self._n,
+                    self._flat(zbuf, zmask))
             elif do_query:
-                flat = np.concatenate([qbuf.ravel(), qmask.ravel()])
-                est = self._tick_query(self._counts, self._n, flat)
-        else:
+                est = self._launch(self._tick_query, self._counts, self._n,
+                                   self._flat(qbuf, qmask))
+        elif do_ingest or do_query:
+            # Only the halves this tick runs are shipped, each with its
+            # tenant-axis sharding.
             sh_z, sh_zm, sh_q, sh_qm = self._in_shardings
-            zargs = (jax.device_put(zbuf, sh_z),
-                     jax.device_put(zmask, sh_zm))
-            qargs = (jax.device_put(qbuf.reshape(-1, self.dim), sh_q),
-                     jax.device_put(qmask.reshape(-1), sh_qm))
+            with span("storm.gw.flatten") as sp:
+                zargs = (jax.device_put(zbuf, sh_z),
+                         jax.device_put(zmask, sh_zm)) if do_ingest else ()
+                qargs = (jax.device_put(qbuf.reshape(-1, self.dim), sh_q),
+                         jax.device_put(qmask.reshape(-1), sh_qm)
+                         ) if do_query else ()
+                sp.set_metadata(h2d_bytes=sum(a.nbytes
+                                              for a in zargs + qargs))
             if do_ingest and do_query:
-                self._counts, self._n, est = self._tick_full(
-                    self._counts, self._n, *zargs, *qargs)
+                self._counts, self._n, est = self._launch(
+                    self._tick_full, self._counts, self._n, *zargs, *qargs)
             elif do_ingest:
-                self._counts, self._n = self._tick_ingest(
-                    self._counts, self._n, *zargs)
-            elif do_query:
-                est = self._tick_query(self._counts, self._n, *qargs)
-        points = sum(take for *_, take in placements)
+                self._counts, self._n = self._launch(
+                    self._tick_ingest, self._counts, self._n, *zargs)
+            else:
+                est = self._launch(self._tick_query, self._counts, self._n,
+                                   *qargs)
         return InflightTick(tick=self.ticks, est=est, placements=placements,
                             completes=completes, ingest_done=ingest_done,
                             rows=rows, points=points)
+
+    @staticmethod
+    def _flat(*parts) -> np.ndarray:
+        """The tick's ONE fused host buffer: ``parts`` raveled end to end."""
+        with span("storm.gw.flatten") as sp:
+            flat = np.concatenate([p.ravel() for p in parts])
+            sp.set_metadata(h2d_bytes=flat.nbytes)
+        return flat
+
+    @staticmethod
+    def _launch(program, *args):
+        """Call a tick program (async dispatch: its outputs are futures)."""
+        with span("storm.gw.launch", program=program.__name__):
+            return program(*args)
 
     def _run_fits(self) -> List[FitResult]:
         """Drain the fit queue against the POST-tick counters.
@@ -1024,19 +1108,30 @@ class StormGateway:
         has landed — "between ticks" in the stage pipeline, reading the
         freshest served counters.
         """
-        results: List[QueryResult] = []
         if inflight.est is not None:
-            losses = np.asarray(inflight.est).reshape(self.tenants,
-                                                      self.query_slots)
-            for st, req_off, t, slot_off, take in inflight.placements:
-                st.out[req_off:req_off + take] = \
-                    losses[t, slot_off:slot_off + take]
-        for st in inflight.completes:
-            results.append(QueryResult(st.req.rid, st.req.tenant, st.out,
-                                       status=st.status))
+            with span("storm.gw.readback", d2h_bytes=inflight.est.nbytes):
+                losses = np.asarray(inflight.est).reshape(self.tenants,
+                                                          self.query_slots)
+        results: List[QueryResult] = []
+        if inflight.placements or inflight.completes:
+            with span("storm.gw.scatter"):
+                for st, req_off, t, slot_off, take in inflight.placements:
+                    st.out[req_off:req_off + take] = \
+                        losses[t, slot_off:slot_off + take]
+                results = [QueryResult(st.req.rid, st.req.tenant, st.out,
+                                       status=st.status)
+                           for st in inflight.completes]
+                # The finished requests' bookkeeping is released here, in
+                # the span, not wherever the caller drops the tick: at
+                # serving sizes that takes about a millisecond.
+                inflight.placements.clear()
+                inflight.completes.clear()
         self.rows_ingested += inflight.rows
         self.points_served += inflight.points
-        fits = self._run_fits() if self._fit_q else []
+        fits: List[FitResult] = []
+        if self._fit_q:
+            with span("storm.gw.fits", fits=len(self._fit_q)):
+                fits = self._run_fits()
         return TickReport(tick=inflight.tick, results=results,
                           rows_ingested=inflight.rows,
                           points_served=inflight.points,
