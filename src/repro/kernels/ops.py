@@ -122,12 +122,26 @@ def paired_hash_histogram_banked(
                                                          interpret=interpret)
 
 
+def query_path(mode: str, dim: int,
+               points_per_table: Optional[int] = None) -> str:
+    """Which banked query a call runs: ``"ref"`` (the jnp oracle),
+    ``"tenant_major"`` (the routed-fetch kernel, DESIGN.md §3.3) or
+    ``"one_hot"``. ``dim`` is the augmented query width; the choice is
+    static, so callers can record it at trace time."""
+    if mode == "ref" or (mode == "auto" and not _on_tpu() and dim < 64):
+        return "ref"
+    if query_kernel.tenant_major(points_per_table):
+        return "tenant_major"
+    return "one_hot"
+
+
 def sketch_query(
     q: Array,
     w: Array,
     counts: Array,
     mode: str = "auto",
     sketch_idx: Optional[Array] = None,
+    points_per_table: Optional[int] = None,
 ) -> Array:
     """Batched RACE query: ``(m,)`` mean counts at the query codes.
 
@@ -138,20 +152,38 @@ def sketch_query(
     With ``sketch_idx`` (``(m,)`` int32) the query is *banked*: ``counts`` is
     a ``(S, R, B)`` stack and point ``i`` gathers from table
     ``sketch_idx[i]`` — one fused call serves S tenants (DESIGN.md §9).
+    ``points_per_table=Q`` (static) is the tenant-major special case, point
+    ``i`` reads table ``i // Q``: it reads each table once per call where
+    ``query_path`` says ``"tenant_major"``, and is the same one-hot query
+    otherwise.
     """
-    if sketch_idx is not None:
+    if sketch_idx is not None or points_per_table is not None:
         if counts.ndim != 3:
             raise ValueError(
-                f"sketch_idx requires banked (S, R, B) counts; got shape "
+                f"banked queries need (S, R, B) counts; got shape "
                 f"{counts.shape}"
             )
-        if mode == "ref" or (
-            mode == "auto" and not _on_tpu() and q.shape[-1] < 64
-        ):
+        path = query_path(mode, q.shape[-1], points_per_table)
+        if points_per_table is not None:
+            if sketch_idx is not None:
+                raise ValueError("give sketch_idx or points_per_table, "
+                                 "not both")
+            if -(-q.shape[0] // points_per_table) > counts.shape[0]:
+                raise ValueError(
+                    f"{q.shape[0]} points at {points_per_table} per table "
+                    f"need more than the bank's {counts.shape[0]} tables"
+                )
+            if path != "tenant_major":
+                sketch_idx = (jnp.arange(q.shape[0], dtype=jnp.int32)
+                              // points_per_table)
+                points_per_table = None
+        if path == "ref":
             return ref.sketch_query_banked(q, w, counts, sketch_idx)
         interpret = mode == "interpret" or (mode == "auto" and not _on_tpu())
-        return query_kernel.sketch_query_banked(q, w, counts, sketch_idx,
-                                                interpret=interpret)
+        return query_kernel.sketch_query_banked(
+            q, w, counts, sketch_idx, points_per_table=points_per_table,
+            interpret=interpret,
+        )
     if counts.ndim != 2:
         raise ValueError(
             f"banked (S, R, B) counts need a sketch_idx; got shape "
@@ -193,7 +225,8 @@ def build_sketch(
     return sketch_lib.Sketch(counts=counts, n=n)
 
 
-@functools.partial(jax.jit, static_argnames=("paired", "mode"))
+@functools.partial(jax.jit,
+                   static_argnames=("paired", "mode", "points_per_table"))
 def query_theta_with_weights(
     sk,
     w: Array,
@@ -201,6 +234,7 @@ def query_theta_with_weights(
     paired: bool = True,
     mode: str = "auto",
     sketch_idx: Optional[Array] = None,
+    points_per_table: Optional[int] = None,
 ) -> Array:
     """Fused surrogate-risk estimate with pre-transposed kernel weights.
 
@@ -218,17 +252,26 @@ def query_theta_with_weights(
     single :class:`~repro.core.sketch.Sketch`; then ``sketch_idx`` (``(m,)``
     int32, one entry per 2-D ``theta_tilde`` row) routes each point to its
     table and the estimator denominator is that sketch's own ``n`` — one
-    fused ``F·(2k+1)``-point call serves many tenants (DESIGN.md §9).
+    fused ``F·(2k+1)``-point call serves many tenants (DESIGN.md §9). A
+    caller whose batch is tenant-major gives the static
+    ``points_per_table=Q`` instead (row ``i`` reads table ``i // Q``), which
+    lets the kernel fetch each table once (:func:`sketch_query`).
     """
     banked = isinstance(sk, sketch_lib.SketchBank)
-    if banked != (sketch_idx is not None):
-        raise ValueError("sketch_idx must be given iff sk is a SketchBank")
+    routed = sketch_idx is not None or points_per_table is not None
+    if banked != routed:
+        raise ValueError("sketch_idx or points_per_table must be given iff "
+                         "sk is a SketchBank")
     q = lsh.augment_query(lsh.normalize_query(theta_tilde))
     if banked:
         if theta_tilde.ndim != 2:
             raise ValueError("banked queries need a (m, dim) theta batch")
         mean_count = sketch_query(q, w, sk.counts, mode=mode,
-                                  sketch_idx=sketch_idx)
+                                  sketch_idx=sketch_idx,
+                                  points_per_table=points_per_table)
+        if sketch_idx is None:
+            sketch_idx = (jnp.arange(q.shape[0], dtype=jnp.int32)
+                          // points_per_table)
         n_per = sk.n[sketch_idx]
     else:
         mean_count = sketch_query(jnp.atleast_2d(q), w, sk.counts, mode=mode)

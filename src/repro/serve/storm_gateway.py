@@ -11,7 +11,8 @@ request classes over fixed engine ticks:
   stack — the grid-over-S kernel on TPU, the vmapped oracle elsewhere).
 * **query** — surrogate-loss evaluation of a theta batch (a client fleet's
   candidates) against that tenant's sketch. All pending points coalesce into
-  ONE banked ``ops.query_theta_with_weights(bank, ..., sketch_idx)`` call.
+  ONE banked ``ops.query_theta_with_weights(bank, ..., points_per_table)``
+  call.
 * **fit** — train a tenant cohort end-to-end from its served counters: one
   ``erm.fit_many`` over the cohort's live sub-bank, for any registered
   surrogate whose insert flavor matches the gateway's. Fits drain between
@@ -446,6 +447,9 @@ class StormGateway:
         self.queries_refused = 0
         self.fits_refused = 0
         self._trace_events = 0  # fallback trace counter (see trace_count)
+        # Program name -> the banked query schedule it compiled with
+        # (``ops.query_path``), recorded when the program is traced.
+        self.query_paths: Dict[str, str] = {}
 
         # Privacy layer (DESIGN.md §15). eps=inf / no policy builds NOTHING:
         # the non-private tick programs below are the whole gateway, so the
@@ -622,9 +626,10 @@ class StormGateway:
 
     # -- the fused tick -----------------------------------------------------
 
-    def _counting(self, fn):
+    def _counting(self, fn, query_path: Optional[str] = None):
         """Bump the fallback trace counter, under a ``storm.gw.trace``
-        span, when ``fn``'s body is traced.
+        span, when ``fn``'s body is traced; a program with a query half
+        records its ``query_path`` in :attr:`query_paths` and on the span.
 
         Both are Python side effects, so under ``jax.jit`` they run once per
         trace (cache miss), never per call — exactly the event
@@ -633,10 +638,14 @@ class StormGateway:
         XLA's compile after it). The wrapper keeps ``fn``'s name, which
         ``jax.jit`` gives the program.
         """
+        stats = {} if query_path is None else {"query_path": query_path}
+
         @functools.wraps(fn)
         def wrapped(*args):
             self._trace_events += 1
-            with span("storm.gw.trace", program=fn.__name__):
+            if query_path is not None:
+                self.query_paths[fn.__name__] = query_path
+            with span("storm.gw.trace", program=fn.__name__, **stats):
                 return fn(*args)
         return wrapped
 
@@ -654,6 +663,7 @@ class StormGateway:
         dtype = self.count_dtype
         s, dim, in_dim = self.tenants, self.dim, self.ingest_dim
         i_cap, q_cap = self.ingest_slots, self.query_slots
+        path = ops.query_path(mode, self.params.dim, points_per_table=q_cap)
 
         def ingest_half(counts, n, zbuf, zmask):
             # ONE fused banked insert over the (S, I, dim) stack. Narrow
@@ -674,13 +684,11 @@ class StormGateway:
 
         def query_half(counts, n, qbuf, qmask):
             # ONE banked call; tenant-major slots route row i to table
-            # i // Q (the member-major contract, member_map = arange(S)).
-            idx = fleet.member_point_idx(
-                jnp.arange(counts.shape[0], dtype=jnp.int32), qbuf.shape[0]
-            )
+            # i // Q (the member-major contract, member_map = arange(S)),
+            # over the local tenants on a mesh too.
             est = ops.query_theta_with_weights(
                 sketch_lib.SketchBank(counts=counts, n=n),
-                w, qbuf, paired=paired, mode=mode, sketch_idx=idx,
+                w, qbuf, paired=paired, mode=mode, points_per_table=q_cap,
             )
             return jnp.where(qmask > 0, est, 0.0)
 
@@ -711,8 +719,9 @@ class StormGateway:
             def tick_query(counts, n, flat):
                 return query_half(counts, n, *unpack_query(flat, 0))
 
-            return tuple(jax.jit(self._counting(f))
-                         for f in (tick_full, tick_ingest, tick_query))
+            return (jax.jit(self._counting(tick_full, path)),
+                    jax.jit(self._counting(tick_ingest)),
+                    jax.jit(self._counting(tick_query, path)))
 
         def tick_full(counts, n, zbuf, zmask, qbuf, qmask):
             counts, n = ingest_half(counts, n, zbuf, zmask)
@@ -736,15 +745,15 @@ class StormGateway:
         self._in_shardings = sharding_specs.named(
             self.mesh, sharding_specs.gateway_input_specs(self.axis))
 
-        def shard(fn, n_in, n_out):
+        def shard(fn, n_in, n_out, query_path=None):
             return jax.jit(self._counting(jax.shard_map(
                 fn, mesh=self.mesh,
                 in_specs=(bank_spec,) * n_in,
                 out_specs=(bank_spec,) * n_out if n_out > 1 else bank_spec,
-            )))
+            ), query_path))
 
-        return (shard(tick_full, 6, 3), shard(tick_ingest, 4, 2),
-                shard(tick_query, 4, 1))
+        return (shard(tick_full, 6, 3, path), shard(tick_ingest, 4, 2),
+                shard(tick_query, 4, 1, path))
 
     def _build_private_tick(self):
         """The ONE extra fixed program of a finite privacy policy.
@@ -787,7 +796,7 @@ class StormGateway:
             )
             return released, jnp.where(qmask > 0, est, 0.0)
 
-        return jax.jit(self._counting(tick_query_private))
+        return jax.jit(self._counting(tick_query_private, "ref"))
 
     def _pack_ingest(self):
         s, i_cap, dim = self.tenants, self.ingest_slots, self.ingest_dim

@@ -20,3 +20,9 @@ def _sub_jaxprs(v):
     elif isinstance(v, (list, tuple)):
         for x in v:
             yield from _sub_jaxprs(x)
+
+
+def pallas_kernels(jaxpr):
+    """Names of the kernel functions of every ``pallas_call`` in a jaxpr."""
+    return [eqn.params["jaxpr"].debug_info.func_src_info.split()[0]
+            for eqn in all_eqns(jaxpr) if eqn.primitive.name == "pallas_call"]

@@ -154,7 +154,8 @@ def test_a_trace_span_per_program_trace_and_none_on_a_repeat(params,
 
     _, spans, stats = _profiled(tmp_path, two_query_ticks)
     assert _names(spans).count("storm.gw.trace") == 1
-    assert stats["storm.gw.trace"] == [{"program": "tick_query"}]
+    assert stats["storm.gw.trace"] == [{"program": "tick_query",
+                                        "query_path": "ref"}]
     assert _names(spans).count("storm.gw.launch") == 2
     first_launch = next(e for e in spans if e[0] == "storm.gw.launch")
     traced = next(e for e in spans if e[0] == "storm.gw.trace")

@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _jaxpr import all_eqns
+from _jaxpr import all_eqns, pallas_kernels
 
 from repro.core import lsh, sketch as sketch_lib
 from repro.kernels import ops, ref
@@ -171,6 +171,81 @@ class TestTiledQuery:
         est_c = sketch_lib.query_theta(sk, params, tt, paired=True)
         np.testing.assert_allclose(np.asarray(est_k), np.asarray(est_c),
                                    rtol=1e-5)
+
+
+# (S, Q, R, counter dtype, block_m, m): S in {1, 8, 64}, Q in {8, 16, 64,
+# 128}, R below and above one 512-row tile, m off a multiple of the query
+# tile (block_m=48 at Q=16 tiles 3 tables; m=100 ends inside a table) and Q
+# above block_m (one table split over tiles).
+TENANT_MAJOR_CASES = [
+    (1, 8, 40, jnp.int32, 128, None),
+    (8, 16, 40, jnp.int16, 128, None),
+    (8, 64, 600, jnp.int8, 128, None),
+    (64, 16, 600, jnp.int32, 128, None),
+    (64, 8, 40, jnp.int8, 128, None),
+    (1, 128, 600, jnp.int16, 128, None),
+    (8, 128, 40, jnp.int32, 64, None),
+    (8, 16, 40, jnp.int32, 48, None),
+    (8, 16, 600, jnp.int16, 128, 100),
+]
+
+
+class TestTenantMajorQuery:
+    """``points_per_table=Q`` (point i reads table i // Q) fetches only each
+    query tile's tables; integer counts keep it bit-equal to the one-hot
+    kernel and the oracle for every counter width."""
+
+    @staticmethod
+    def _case(s, q, r, dtype, m, p=3, d=7):
+        m = s * q if m is None else m
+        kq, kw, kc = jax.random.split(jax.random.PRNGKey(s * q + r), 3)
+        # Row sums stay below 2^24, where f32 adds integers exactly.
+        hi = {jnp.int8: 127, jnp.int16: 16384}.get(dtype, 1 << 14)
+        return (jax.random.normal(kq, (m, d)),
+                jax.random.normal(kw, (p, d, r)),
+                jax.random.randint(kc, (s, r, 1 << p), 0, hi).astype(dtype),
+                jnp.arange(m, dtype=jnp.int32) // q)
+
+    @pytest.mark.parametrize("s,q,r,dtype,block_m,m", TENANT_MAJOR_CASES)
+    def test_bit_equal_to_one_hot_and_oracle(self, s, q, r, dtype, block_m, m):
+        qv, w, counts, idx = self._case(s, q, r, dtype, m)
+        got = query_kernel.sketch_query_banked(
+            qv, w, counts, points_per_table=q, block_m=block_m,
+            interpret=True)
+        one_hot = query_kernel.sketch_query_banked(qv, w, counts, idx,
+                                                   interpret=True)
+        want = ref.sketch_query_banked(qv, w, counts, idx)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(one_hot))
+
+    @pytest.mark.parametrize("q,path,kernel", [
+        (16, "tenant_major", "_query_kernel_tenant_major"),
+        (12, "one_hot", "_query_kernel"),
+    ])
+    def test_ops_engages_only_at_multiples_of_eight(self, q, path, kernel):
+        qv, w, counts, idx = self._case(4, q, 40, jnp.int32, None)
+        assert ops.query_path("interpret", qv.shape[1], q) == path
+        assert ops.query_path("ref", qv.shape[1], q) == "ref"
+        call = lambda *a: ops.sketch_query(*a, mode="interpret",  # noqa: E731
+                                           points_per_table=q)
+        assert pallas_kernels(jax.make_jaxpr(call)(qv, w, counts)) == [kernel]
+        np.testing.assert_array_equal(
+            np.asarray(call(qv, w, counts)),
+            np.asarray(ref.sketch_query_banked(qv, w, counts, idx)))
+
+    def test_rejects_bad_layouts(self):
+        qv, w, counts, idx = self._case(4, 12, 40, jnp.int32, None)
+        with pytest.raises(ValueError, match="multiple of 8"):
+            query_kernel.sketch_query_banked(qv, w, counts,
+                                             points_per_table=12)
+        with pytest.raises(ValueError, match="exactly one"):
+            query_kernel.sketch_query_banked(qv, w, counts, idx,
+                                             points_per_table=16)
+        with pytest.raises(ValueError, match="tables"):  # 48 points > 4·8
+            ops.sketch_query(qv, w, counts, points_per_table=8)
+        with pytest.raises(ValueError, match="not both"):
+            ops.sketch_query(qv, w, counts, sketch_idx=idx,
+                             points_per_table=12)
 
 
 class TestBuildSketchPaired:

@@ -19,6 +19,7 @@ import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 from jax.sharding import Mesh  # noqa: E402
 
+from _jaxpr import pallas_kernels  # noqa: E402
 from repro.core import fleet, lsh, regression, sketch as sketch_lib  # noqa: E402
 from repro.data import datasets  # noqa: E402
 from repro.kernels import ops  # noqa: E402
@@ -216,6 +217,58 @@ class TestQuery:
             gw.sketch_of(0), w, jnp.asarray(thetas), paired=True
         ))
         np.testing.assert_array_equal(done[0].losses, want)
+
+
+class TestQueryPath:
+    """Tenant-major slots engage the routed-fetch query kernel (DESIGN.md
+    §10.2); the gateway records the path each program compiled with."""
+
+    @pytest.mark.parametrize("q,path,kernel", [
+        (16, "tenant_major", "_query_kernel_tenant_major"),
+        (12, "one_hot", "_query_kernel"),
+    ])
+    def test_programs_record_path_and_answers_stay_bit_equal(
+            self, params, q, path, kernel):
+        gw = StormGateway(params, S, query_slots=q, ingest_slots=64,
+                          mode="interpret")
+        for t, z in enumerate(_streams()):
+            gw.submit(IngestRequest(rid=t, tenant=t, z=z))
+        while gw.pending:
+            gw.tick()
+        thetas = _thetas(q=q + 3)  # spills into a second query tick
+        for t in range(S):
+            gw.submit(QueryRequest(rid=t, tenant=t, thetas=thetas[t]))
+        results = {r.rid: r for r in gw.run_until_idle()}
+        assert gw.query_paths == {"tick_query": path}
+        flat = jnp.zeros((S * q * (D + 1),), jnp.float32)
+        program = jax.make_jaxpr(gw._tick_query)(gw.bank.counts, gw.bank.n,
+                                                 flat)
+        assert pallas_kernels(program) == [kernel]
+        w = ops.from_lsh_params(params)
+        for t in range(S):
+            want = np.asarray(ops.query_theta_with_weights(
+                gw.sketch_of(t), w, jnp.asarray(thetas[t]), paired=True,
+                mode="interpret"))
+            np.testing.assert_array_equal(results[t].losses, want)
+
+    def test_fleet_closure_keeps_one_hot(self, params, monkeypatch):
+        """A banked loss closure routes by an arbitrary member map, so on
+        the chip it runs the one-hot kernel. Traced, not run, with the
+        backend check steered to the chip's branch."""
+        bank = sketch_lib.bank_of([sketch_lib.Sketch(
+            counts=jnp.zeros((params.rows, params.buckets), jnp.int32),
+            n=jnp.int32(1))] * 3)
+        jax.clear_caches()  # no cached CPU trace stands in for the chip's
+        monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+        try:
+            loss = fleet.make_loss_fn(bank, params, engine="kernel",
+                                      member_map=jnp.array([2, 0, 1]))
+            program = jax.make_jaxpr(loss)(jnp.ones((6, D)))
+            assert ops.query_path("auto", params.dim) == "one_hot"
+        finally:
+            monkeypatch.undo()
+            jax.clear_caches()
+        assert pallas_kernels(program) == ["_query_kernel"]
 
 
 class TestEngineDiscipline:
