@@ -3,7 +3,9 @@
 A cell names a configuration and a traffic mix; each lives in a file of its
 own, found by name:
 
-* ``configs/<config>.json``: the deployment's sizes;
+* ``configs/<config>.json``: the deployment's sizes, whose ``deployment``
+  names the module ``deployments/<deployment>.py`` that builds and checks
+  it;
 * ``traffic/<traffic>.json``: the mix's parameters, whose ``kind`` names the
   generator ``traffic/<kind>.py``;
 * ``metrics/<metric>.py``: the reader of one per-layer metric, a function
@@ -11,6 +13,17 @@ own, found by name:
 * ``peaks/<device kind, spaces as _>.json``: the chip's published peaks.
 
 Adding a cell or a metric therefore adds files and entries, and edits none.
+
+A deployment module is what differs between kinds of deployment (the
+program it builds, its payloads, its reference); ``run.py`` keeps what every
+cell shares (devices, compile cache, traffic, the timed window, tracing,
+metric readers and the result line) and reads no configuration key but
+``deployment``. The module's ``build(cell, devices, seed, rehearse)``
+returns an object with ``server`` (driven by the traffic generators and the
+window), ``carried`` (``{INGEST: rows, QUERY: points}`` per logged
+request), ``warm(programs, seed)``, ``layout()``, ``counters()`` and
+``check(log, seed, unanswered, control)`` (the numbers ``check.correct``
+judges); ``deployments/regression.py`` sets the contract out in full.
 ``rehearsal.json`` holds test-only cells at tiny sizes for CPU rehearsals;
 each reports the metrics of the real cell it names under ``reports_as``.
 """
@@ -78,6 +91,16 @@ def load_module(path: Path):
 def generator(kind: str):
     """The traffic generator module ``traffic/<kind>.py``."""
     return load_module(HERE / "traffic" / f"{kind}.py")
+
+
+def deployment(name):
+    """The deployment module ``deployments/<name>.py``; a configuration
+    without one, or naming none that exists, is refused."""
+    known = sorted(p.stem for p in (HERE / "deployments").glob("*.py"))
+    if name not in known:
+        raise SystemExit(f"no deployment module {name!r} under "
+                         f"storm_bench/deployments/; known: {known}")
+    return load_module(HERE / "deployments" / f"{name}.py")
 
 
 def metric_reader(name: str) -> Callable:

@@ -3,14 +3,16 @@
     python3 storm_bench/run.py --workload <name> --seed <n> --seconds <s> \\
         --trace <0|1>
 
-Set-up builds the cell's gateway on the chip from the seed, fills every
-tenant with ``fill_rows`` rows through the gateway, and warms the tick
-programs the cell's traffic uses. The window then drives the gateway's
-double-buffered engine loop (``serve.py``) with the cell's traffic
-(``traffic/<kind>.py``) for ``--seconds``. Requests due in the window are
-followed to completion; then the device's peak memory is read, the
-program's state is copied out and freed, and a sample of what the window
-served is compared with the plain reference (``check.py``).
+Set-up builds the cell's deployment on the cell's chips from the seed,
+through the module its configuration names (``deployments/<name>.py``,
+``cells.py``): for the regression deployment a gateway whose tenants it
+fills with ``fill_rows`` rows, and whose tick programs the cell's traffic
+uses it warms. The window then drives the deployment's engine loop
+(``serve.py``) with the cell's traffic (``traffic/<kind>.py``) for
+``--seconds``. Requests due in the window are followed to completion; then
+the devices' peak memory is read, and the deployment copies out what it
+compares, frees the program's state, and compares a sample of what the
+window served with its plain reference (``check.py``).
 
 ``--trace 0`` reports the cell's end-to-end metrics, ``--trace 1`` its
 per-layer metrics from a profiler trace of the window. The last line of
@@ -20,7 +22,9 @@ are the last lines of standard error and the last key of that object.
 Without a TPU, or with fewer chips than the cell asks for, the run exits
 non-zero and prints no result. Options the benchmark's own runs do not use:
 ``--rehearse`` takes a test-only cell from ``rehearsal.json`` and runs on
-the CPU (Pallas in interpret mode) with its metrics under ``cpu_metrics``;
+the CPU (Pallas in interpret mode; a cell of several chips needs as many
+CPU devices, ``--xla_force_host_platform_device_count``) with its metrics
+under ``cpu_metrics``;
 ``--control`` puts the reference at one precision step below the stated one
 in the program's place for the comparison; ``--sweep r1,r2,..`` measures an
 open loop at several rates after one set-up, with no check.
@@ -33,7 +37,6 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
-import functools  # noqa: E402
 import json  # noqa: E402
 import sys  # noqa: E402
 from pathlib import Path  # noqa: E402
@@ -45,8 +48,8 @@ for p in (ROOT / "src", ROOT):
 
 import numpy as np  # noqa: E402
 
-from storm_bench import cells, check, payloads  # noqa: E402
-from storm_bench.serve import (INGEST, QUERY, SETUP, WINDOW,  # noqa: E402
+from storm_bench import cells, check  # noqa: E402
+from storm_bench.serve import (INGEST, QUERY, WINDOW,  # noqa: E402
                                annotate, clock)
 
 
@@ -78,74 +81,11 @@ def devices(cell, rehearse: bool):
     return devs[:cell.chips]
 
 
-def hyperplanes(cfg: dict):
-    """The deployment's hash family, ``(R, p, dim + 2)`` float32, made on
-    the device in one call from the configuration's ``hash_seed``.
-
-    A deployment fixes its family for the life of its sketches. The tick
-    programs hold it as a compiled-in constant, so a family drawn from the
-    run's seed would make every run compile anew. The benchmark draws it
-    itself (the same draw as ``lsh.init_srp``), because the reference may
-    take no table that the program has made.
-    """
-    import jax
-
-    shape = (cfg["rows"], cfg["planes"], cfg["features"] + 3)
-    return jax.jit(lambda k: jax.random.normal(k, shape))(
-        jax.random.key(cfg["hash_seed"]))
-
-
-def build(args, cell):
-    import jax.numpy as jnp
-
-    from repro.core import lsh
-    from repro.serve.storm_gateway import StormGateway
-    from storm_bench.serve import Server
-
-    cfg, traffic = cell.config, cell.traffic
-    w = hyperplanes(cfg)
-    gw = StormGateway(lsh.LSHParams(projections=w), cfg["tenants"],
-                      query_slots=cfg["query_slots"],
-                      ingest_slots=cfg["ingest_slots"],
-                      count_dtype=jnp.dtype(cfg["count_dtype"]),
-                      mode="interpret" if args.rehearse else "kernel")
-    rows = payloads.row_pool(payloads.rng_of(args.seed, 1), cfg["features"],
-                             traffic["ingest_rows"], cfg["noise"],
-                             cfg["condition"])
-    thetas = payloads.theta_pool(payloads.rng_of(args.seed, 2),
-                                 cfg["features"] + 1,
-                                 traffic.get("query_points", 1))
-    return Server(gw, rows, thetas), np.asarray(w)
-
-
-def prepare(server, cell, programs, seed: int) -> None:
-    """Fill every tenant, then run each tick program the traffic uses
-    twice, so that the window compiles and loads nothing."""
-    s = cell.config["tenants"]
-    per = cell.traffic["ingest_rows"]
-    rng = payloads.rng_of(seed, 4)
-
-    def serve(reqs):
-        with server.lock:
-            for kind, tenant in reqs:
-                server.submit(kind, tenant,
-                              int(rng.integers(payloads.POOL_BLOCKS)),
-                              clock(), SETUP)
-        server.run(lambda: True)
-
-    fill = cell.traffic.get("fill_rows", 0) // per
-    serve([(INGEST, t) for _ in range(fill) for t in range(s)])
-    warm = {"ingest": [(INGEST, 0)], "query": [(QUERY, 0)],
-            "full": [(INGEST, 0), (QUERY, 0)]}
-    for name in sorted(programs):
-        for _ in range(2):
-            serve(warm[name])
-
-
 def e2e_metrics(log, t0: float, seconds: float, setup_s: float,
-                query_points: int, ingest_rows: int, open_loop: bool) -> dict:
+                carried: dict, open_loop: bool) -> dict:
     """An open loop counts every request due in the window, followed to
-    completion; a closed loop counts what was answered inside the window."""
+    completion; a closed loop counts what was answered inside the window.
+    ``carried`` gives the points of a query and the rows of an ingest."""
     win = log["phase"] == WINDOW
     ok = np.isfinite(log["done"]) & ~log["failed"]
     if open_loop:
@@ -158,10 +98,12 @@ def e2e_metrics(log, t0: float, seconds: float, setup_s: float,
         lat = np.where(ok[q], log["done"][q] - log["due"][q], np.inf)
         values["query_p99_ms"] = float(np.percentile(lat, 99) * 1e3)
         values["query_points_per_s"] = float(
-            (counted & (log["kind"] == QUERY)).sum() * query_points / seconds)
+            (counted & (log["kind"] == QUERY)).sum() * carried[QUERY]
+            / seconds)
     if (log["kind"][win] == INGEST).any():
         values["ingest_rows_per_s"] = float(
-            (counted & (log["kind"] == INGEST)).sum() * ingest_rows / seconds)
+            (counted & (log["kind"] == INGEST)).sum() * carried[INGEST]
+            / seconds)
     return values
 
 
@@ -192,6 +134,7 @@ def sweep(args, cell, server, gen_mod, rates) -> None:
 def main(argv=None) -> dict:
     args = parse(argv)
     cell = cells.load_cell(args.workload, rehearsal=args.rehearse)
+    deployment = cells.deployment(cell.config.get("deployment"))
     setup = {"imported": clock() - T_START}  # seconds from start, per phase
     devs = devices(cell, args.rehearse)
     setup["devices"] = clock() - T_START
@@ -206,12 +149,13 @@ def main(argv=None) -> dict:
     from storm_bench import trace as trace_lib
 
     gen_mod = cells.generator(cell.traffic["kind"])
-    server, w = build(args, cell)
+    built = deployment.build(cell, devs, args.seed, args.rehearse)
+    server = built.server
     setup["built"] = clock() - T_START
     rates = [float(r) for r in args.sweep.split(",")] if args.sweep else []
     gen = gen_mod.Driver(cell.traffic, cell.config, args.seed, args.seconds,
                          *rates[:1])
-    prepare(server, cell, gen.programs, args.seed)
+    built.warm(gen.programs, args.seed)
     setup["warm"] = clock() - T_START
     if rates:
         return sweep(args, cell, server, gen_mod, rates)
@@ -241,54 +185,30 @@ def main(argv=None) -> dict:
         stopper.join()
     ticks_in_window = [t for t in server.ticks
                        if t0 <= t[0] < t0 + args.seconds]
-    counters = {k: v for k, v in server.gw.queue_stats().items()
-                if not isinstance(v, list)}
-    peak = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
-               for d in devs)
+    counters = built.counters()
+    layout = built.layout()
+    peaks = [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+             for d in devs]
     device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
-              "count": len(devs), "memory_peak_bytes": peak}
+              "count": len(devs), "memory_peak_bytes": max(peaks)}
 
     log = server.table()
-    cfg, traffic = cell.config, cell.traffic
-    sent = check.rows_sent(log, cfg["tenants"], traffic["ingest_rows"])
-    counted, hash_rows, rids = check.sample(log, cfg["tenants"], cfg["rows"],
-                                            args.seed)
-    tenants_read = sorted(set(counted.tolist())
-                          | set(log["tenant"][rids].tolist()))
-    got = check.program_state(server.gw, tenants_read, sent)
-    got["answers"] = server.answers
     win = log["phase"] == WINDOW
     unanswered = int((win & (~np.isfinite(log["done"]) | log["failed"])
                       ).sum())
-    server.gw = None  # the program's state is freed before the reference
-    import gc
-
-    gc.collect()
-
     t_check = clock()
-    from storm_bench.reference import Reference
-
-    tick_starts = np.asarray([t[0] for t in server.ticks])
-    replay = functools.partial(check.replay, log=log, rows=server.rows,
-                               thetas=server.thetas, counted=counted,
-                               hash_rows=hash_rows, rids=rids,
-                               tick_starts=tick_starts)
-    expected = replay(Reference(w))
-    if args.control:
-        got = check.control_state(*replay(Reference(w, "high"), paths=False),
-                                  sent)
-    numbers = check.compare(expected, got, sent, unanswered, log["tenant"])
+    numbers = built.check(log, args.seed, unanswered, args.control)
     check_s = clock() - t_check
 
-    e2e = e2e_metrics(log, t0, args.seconds, setup_s,
-                      traffic.get("query_points", 0), traffic["ingest_rows"],
-                      traffic["kind"] == "open_loop")
+    e2e = e2e_metrics(log, t0, args.seconds, setup_s, built.carried,
+                      cell.traffic["kind"] == "open_loop")
     result = {"correct": check.correct(numbers),
               "attempted": int(win.sum()),
               "failed": unanswered}
     run_info = {"generator": gen.lag_summary(), "setup": setup,
                 "check_s": check_s,
                 "ticks_in_window": len(ticks_in_window),
+                "layout": layout, "memory_peak_bytes_per_chip": peaks,
                 "counters": counters, "e2e": e2e}
     if args.trace:
         events = trace_lib.extract(trace_dir)
@@ -298,7 +218,8 @@ def main(argv=None) -> dict:
             if not args.rehearse:
                 raise
             summary = None  # the CPU backend has no device plane
-        run = {"config": cfg, "traffic": traffic, "seconds": args.seconds,
+        run = {"config": cell.config, "traffic": cell.traffic,
+               "seconds": args.seconds,
                "ticks": np.asarray(ticks_in_window).reshape(-1, 4),
                "trace": events, "summary": summary,
                "peaks": cells.peaks(devs[0].device_kind)

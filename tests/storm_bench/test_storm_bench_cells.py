@@ -49,7 +49,10 @@ def test_benchmark_file_keeps_to_the_contract():
         assert 0.01 <= m["bound"] <= 0.25
     layers = {m["layer"] for m in BENCH["per_layer"]}
     assert all(len(x) <= 200 for x in layers)
-    assert all(w["chips"] == 1 for w in BENCH["workloads"])
+    # A cell of four chips runs its deployment over all four (the bank
+    # split over a mesh of exactly the cell's devices: the ``*-4dev``
+    # rehearsals in test_storm_bench_deployments.py).
+    assert all(w["chips"] in (1, 4) for w in BENCH["workloads"])
 
 
 def test_peaks_are_keyed_by_device_kind():
